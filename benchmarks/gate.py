@@ -174,7 +174,7 @@ EXPECTED_RECOVERY = {
     "bit-flip": ("recovered",),
     "truncation": ("typed-error",),
     "save-crash": ("previous-intact",),
-    "pallas-failure": ("degraded", "recovered"),
+    "pallas-failure": ("typed-error",),
     "stuck-neighbor": ("typed-error",),
     "deadline-miss": ("typed-error",),
 }

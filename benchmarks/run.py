@@ -14,6 +14,8 @@ import sys
 import traceback
 from pathlib import Path
 
+from repro.launch import compile_cache
+
 ALL = ["table2", "table3", "fig5", "gradsync", "ckpt", "roofline", "kernels"]
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
@@ -49,6 +51,7 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
     names = args.only.split(",") if args.only else ALL
+    compile_cache.enable()
 
     print("name,value,notes")
     failures = 0

@@ -37,21 +37,25 @@ Derived structure (computed, never hand-coded):
     the *input* commutes with the lifting cascade, which is what lets
     windowed engines reflect-pad raw samples instead of band values.
 
-Four execution primitives implement every engine in the repo:
+Three pairs of execution primitives implement every engine in the repo:
 
   :func:`lift_fwd_axis` / :func:`lift_inv_axis`
       band-policy reference math along one axis of a full array — the
-      oracle (``core.lifting``), the XLA backend, the whole-image fused
-      2D Pallas kernel and the sharded row stage all run this.
+      oracle (``core.lifting``), the XLA backend and the sharded row
+      stage run this.
   :func:`lift_fwd_axis_ext` / :func:`lift_inv_axis_ext`
       interior-only math along one axis of an already-extended array —
-      the body of the tiled/windowed Pallas kernels and the sharded
-      column stage (halo rows exchanged via ``ppermute``).
+      the sharded column stage (halo rows exchanged via ``ppermute``).
+  :func:`lift_fwd_split` / :func:`lift_inv_split`
+      either kind of math, per axis, on pre-split polyphase components
+      — the body of every Pallas kernel (Mosaic cannot lay out the
+      in-kernel even/odd split; :func:`polyphase_split` /
+      :func:`polyphase_merge` run it in XLA around the kernel).
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -644,6 +648,103 @@ def lift_inv_axis_ext(
         s_ext, d_ext, resolved_steps(sch, mode), axis, m, inverse=True
     )
     return merge_axis(even, odd, axis, 2 * (s_ext.shape[axis] - 2 * m))
+
+
+# ---------------------------------------------------------------------------
+# Pre-split N-D levels: the kernel bodies.  Mosaic cannot lay out the
+# minor-axis (n/2, 2) reshape of :func:`split_axis` (nor the stack+reshape
+# of :func:`merge_axis`), so the Pallas engines split and merge polyphase
+# components in their XLA wrappers and run only the cascades in-kernel.
+# Components and bands share one code order: bit j of the code means odd
+# samples (forward input) / highpass (band) along axis -(j+1) — the
+# ``core.lifting`` band code — so the forward cascade turns component c
+# into band c in place.
+# ---------------------------------------------------------------------------
+
+
+def polyphase_split(x: Array, ndim: int) -> List[Array]:
+    """The ``2**ndim`` polyphase components of ``x`` over its last
+    ``ndim`` axes, in code order (bit j = odd samples along axis -(j+1))."""
+    comps = [x]
+    for j in range(ndim):
+        nxt: List[Array] = [None] * (2 * len(comps))  # type: ignore[list-item]
+        for c, a in enumerate(comps):
+            nxt[c], nxt[c | (1 << j)] = split_axis(a, a.ndim - 1 - j)
+        comps = nxt
+    return comps
+
+
+def polyphase_merge(comps: List[Array], shape: Tuple[int, ...]) -> Array:
+    """Inverse of :func:`polyphase_split`; ``shape`` is the trailing
+    sample shape (one entry per split axis)."""
+    ndim = len(shape)
+    cur = list(comps)
+    for j in reversed(range(ndim)):
+        half = 1 << j
+        cur = [
+            merge_axis(cur[c], cur[c | half], cur[c].ndim - 1 - j, shape[-(j + 1)])
+            for c in range(half)
+        ]
+    return cur[0]
+
+
+def lift_fwd_split(
+    comps: List[Array], scheme, mode: str, lengths: Tuple[Optional[int], ...]
+) -> List[Array]:
+    """One forward N-D level on pre-split polyphase components.
+
+    ``lengths[j]`` describes axis -(j+1): the signal's sample count there
+    for band-policy reads (:func:`lift_fwd_axis` semantics), or ``None``
+    for interior math on streams extended by ``fwd_margin`` entries per
+    side (:func:`lift_fwd_axis_ext` semantics).  Axes run -1 first, the
+    reference composition order.  Returns the bands in code order.
+    """
+    sch = get_scheme(scheme)
+    steps = resolved_steps(sch, mode)
+    out = list(comps)
+    for j, n in enumerate(lengths):
+        bit = 1 << j
+        for c in range(len(out)):
+            if c & bit:
+                continue
+            e, o = out[c], out[c | bit]
+            axis = e.ndim - 1 - j
+            if n is None:
+                out[c], out[c | bit] = _walk_ext(
+                    e, o, steps, axis, sch.fwd_margin, inverse=False
+                )
+            else:
+                out[c], out[c | bit] = _walk_policy(
+                    e, o, steps, axis, n, inverse=False
+                )
+    return out
+
+
+def lift_inv_split(
+    bands: List[Array], scheme, mode: str, lengths: Tuple[Optional[int], ...]
+) -> List[Array]:
+    """Inverse of :func:`lift_fwd_split`: code-ordered bands (extended by
+    ``inv_margin`` entries per side along ``None`` axes) -> the polyphase
+    components of the core signal, in code order."""
+    sch = get_scheme(scheme)
+    steps = resolved_steps(sch, mode)
+    out = list(bands)
+    for j in reversed(range(len(lengths))):
+        n, bit = lengths[j], 1 << j
+        for c in range(len(out)):
+            if c & bit:
+                continue
+            s, d = out[c], out[c | bit]
+            axis = s.ndim - 1 - j
+            if n is None:
+                out[c], out[c | bit] = _walk_ext(
+                    s, d, steps, axis, sch.inv_margin, inverse=True
+                )
+            else:
+                out[c], out[c | bit] = _walk_policy(
+                    s, d, steps, axis, n, inverse=True
+                )
+    return out
 
 
 def extend_band(

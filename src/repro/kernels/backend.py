@@ -183,59 +183,47 @@ def note_degrade(requested: str, resolved: str, reason: str) -> None:
     )
 
 
-def _host_span(label: str):
+def _host_span(label: str, operand):
     """A kernels-subsystem span — but ONLY outside any jax trace.
 
     ``pallas_guard`` runs both host-side (direct wrapper calls) and at
     trace time (under a caller's ``jax.jit``); a span recorded during
     tracing would measure compile time once and nothing thereafter, so
-    inside a trace this is a null context instead.
+    when the call's operand is a tracer this is a null context instead.
     """
-    try:
-        clean = jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 - jax internals moved; skip the span
-        return contextlib.nullcontext()
-    if not clean:
+    if any(isinstance(a, jax.core.Tracer) for a in jax.tree_util.tree_leaves(operand)):
         return contextlib.nullcontext()
     return obs.span(label, subsystem="kernels")
 
 
-def pallas_guard(resolved: str, label: str, kernel_thunk, xla_thunk):
-    """Run the kernel path with the XLA reference as a safety net.
+def pallas_guard(resolved: str, label: str, operand, kernel_thunk, xla_thunk):
+    """Run a transform on the path ``resolved`` names — and only there.
 
-    The single choke point every public wrapper dispatches through: when
-    ``resolved`` is the XLA backend the reference thunk runs directly;
-    otherwise the Pallas thunk runs, and a compile/launch failure (a
-    Mosaic/Triton lowering bug, an emulator fault, an injected
-    ``kernels.pallas`` chaos fault) degrades to the bit-exact jitted XLA
-    reference with a one-time :class:`BackendDegradeWarning` naming the
-    kernel and the cause — the transform still returns the exact answer,
-    on the slower path, instead of surfacing a runtime internal error.
-
-    Deliberately NOT a correctness net: both paths are bit-exact by
-    construction (tests sweep them), so catching here can only trade
-    performance, never results.  Argument-validation errors are raised
-    by the wrappers BEFORE dispatch and never reach this guard.
+    The single choke point every public wrapper dispatches through: the
+    XLA reference thunk on ``xla``, the Pallas thunk otherwise.  There is
+    no fallback: a kernel that fails to lower, compile or launch (a
+    Mosaic refusal, an emulator fault, an injected ``kernels.pallas``
+    chaos fault) records a ``FaultEvent`` and raises.  Both paths are
+    bit-exact by construction, so a fallback could only ever trade
+    speed — and a silently slower path is exactly what a benchmark must
+    never time.  ``operand`` (the call's input array or pytree) tells a
+    host call from one traced under a caller's jit, for the span.
+    Argument-validation errors are raised by the wrappers BEFORE
+    dispatch and never reach this guard.
     """
     from repro.resilience import inject
 
-    if resolved == "xla":
-        with _host_span(label):
+    with _host_span(label, operand):
+        if resolved == "xla":
             return xla_thunk()
-    try:
-        inject.check("kernels.pallas")
-        with _host_span(label):
+        try:
+            inject.check("kernels.pallas")
             return kernel_thunk()
-    except Exception as e:  # noqa: BLE001 - any lowering/launch failure
-        obs.emit(obs.FaultEvent(
-            subsystem="kernels", error=type(e).__name__, site=label,
-        ))
-        note_degrade(
-            resolved, "xla",
-            f"{label}: kernel path failed ({type(e).__name__}: {e}); "
-            "recomputed on the jitted XLA reference",
-        )
-        return xla_thunk()
+        except Exception as e:
+            obs.emit(obs.FaultEvent(
+                subsystem="kernels", error=type(e).__name__, site=label,
+            ))
+            raise
 
 
 @contextlib.contextmanager
@@ -364,9 +352,11 @@ def fused3d_budget_elems() -> int:
     )
 
 
-# tiled-2D engine defaults: 252 core + 4 halo = 256 — lane-aligned input
-# windows, the dominant DMA of the tiled kernels
-DEFAULT_TILE = 252
+# tiled-2D engine default core tile: 256 samples makes every band tile
+# (128, 128) — Mosaic requires a block that is not the whole array to be
+# (8, 128)-aligned in its two minor dims.  The halo rides in the window
+# input, whose block is always the whole window.
+DEFAULT_TILE = 256
 _MIN_TILE = 4  # tiles are even and >= 4 so every window has a full halo
 
 

@@ -3,10 +3,12 @@
 TPU adaptation of the paper's PE (see DESIGN.md §2): the serial
 delay-line dataflow becomes a blocked-parallel VPU computation over
 halo'd windows.  Each grid cell holds one ``(block_rows, window)`` slice
-of the signal (forward) or of the two bands (inverse) in VMEM and runs
-the scheme's full lifting cascade as interior-only math
-(``schemes.lift_fwd_axis_ext`` / ``lift_inv_axis_ext``) — integer
-adds/subtracts and arithmetic shifts only (multiplierless).
+of the signal's even and odd streams (forward) or of the two bands
+(inverse) in VMEM and runs the scheme's full lifting cascade as
+interior-only math (``schemes.lift_fwd_split`` / ``lift_inv_split``) —
+integer adds/subtracts and arithmetic shifts only (multiplierless).  The
+even/odd split and merge run in the XLA wrapper: Mosaic has no layout
+for them in-kernel.
 
 Cross-tile dependencies (the paper's programmable delays) are resolved
 by OVERLAP, not sequencing: the wrapper (``ops.py``) gathers each cell's
@@ -36,18 +38,26 @@ from repro.core import schemes as S
 from repro.kernels.backend import DEFAULT_BLOCK_PAIRS, DEFAULT_BLOCK_ROWS  # noqa: F401
 
 
-def _fwd_kernel(w_ref, s_ref, d_ref, *, scheme: str, mode: str):
+def _fwd_kernel(e_ref, o_ref, s_ref, d_ref, *, scheme, mode: str):
     """Forward lifting cascade over one halo'd window tile."""
-    s, d = S.lift_fwd_axis_ext(w_ref[:, 0, :], scheme, axis=-1, mode=mode)
-    s_ref[:, 0, :] = s
-    d_ref[:, 0, :] = d
-
-
-def _inv_kernel(s_ref, d_ref, x_ref, *, scheme: str, mode: str):
-    """Inverse lifting cascade over one pair of margin-extended band tiles."""
-    x_ref[:, 0, :] = S.lift_inv_axis_ext(
-        s_ref[:, 0, :], d_ref[:, 0, :], scheme, axis=-1, mode=mode
+    s_ref[...], d_ref[...] = S.lift_fwd_split(
+        [e_ref[0], o_ref[0]], scheme, mode, (None,)
     )
+
+
+def _inv_kernel(s_ref, d_ref, e_ref, o_ref, *, scheme, mode: str):
+    """Inverse lifting cascade over one pair of margin-extended band tiles."""
+    e_ref[...], o_ref[...] = S.lift_inv_split(
+        [s_ref[0], d_ref[0]], scheme, mode, (None,)
+    )
+
+
+def _specs(block_rows: int, wlen: int, block_pairs: int):
+    """Window blocks (tile-major: the tile index never sits in the two
+    minor dims Mosaic tiles) and natural-layout output blocks."""
+    win = pl.BlockSpec((1, block_rows, wlen), lambda r, t: (t, r, 0))
+    out = pl.BlockSpec((block_rows, block_pairs), lambda r, t: (r, t))
+    return win, out
 
 
 @functools.partial(
@@ -55,7 +65,8 @@ def _inv_kernel(s_ref, d_ref, x_ref, *, scheme: str, mode: str):
     static_argnames=("scheme", "mode", "block_rows", "block_pairs", "interpret"),
 )
 def lift_fwd_windows(
-    wins: jax.Array,
+    e_wins: jax.Array,
+    o_wins: jax.Array,
     *,
     scheme: str,
     mode: str,
@@ -63,26 +74,25 @@ def lift_fwd_windows(
     block_pairs: int,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Forward lifting over gathered signal windows.
+    """Forward lifting over gathered polyphase signal windows.
 
-    wins : (rows, n_tiles, 2*block_pairs + 2*halo) — each window carries
-           the scheme's reflect halo on both sides; rows % block_rows == 0.
-    returns (s, d) : (rows, n_tiles, block_pairs) each — the core output
-           pairs of every window.
+    e_wins, o_wins : (n_tiles, rows, block_pairs + 2*fwd_margin) — the
+           even / odd samples of each window, which carries the scheme's
+           reflect halo on both sides; rows % block_rows == 0.
+    returns (s, d) : (rows, n_tiles * block_pairs) each — the core output
+           pairs of every window, in signal order.
     """
-    rows, n_tiles, wlen = wins.shape
-    grid = (rows // block_rows, n_tiles)
-    win_spec = pl.BlockSpec((block_rows, 1, wlen), lambda r, t: (r, t, 0))
-    out_spec = pl.BlockSpec((block_rows, 1, block_pairs), lambda r, t: (r, t, 0))
-    out = jax.ShapeDtypeStruct((rows, n_tiles, block_pairs), wins.dtype)
+    n_tiles, rows, wlen = e_wins.shape
+    win_spec, out_spec = _specs(block_rows, wlen, block_pairs)
+    out = jax.ShapeDtypeStruct((rows, n_tiles * block_pairs), e_wins.dtype)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scheme=scheme, mode=mode),
-        grid=grid,
-        in_specs=[win_spec],
+        grid=(rows // block_rows, n_tiles),
+        in_specs=[win_spec, win_spec],
         out_specs=(out_spec, out_spec),
         out_shape=(out, out),
         interpret=interpret,
-    )(wins)
+    )(e_wins, o_wins)
 
 
 @functools.partial(
@@ -98,25 +108,22 @@ def lift_inv_windows(
     block_rows: int,
     block_pairs: int,
     interpret: bool = False,
-) -> jax.Array:
+) -> Tuple[jax.Array, jax.Array]:
     """Inverse lifting over gathered band windows.
 
-    s_wins, d_wins : (rows, n_tiles, block_pairs + 2*inv_margin) — band
+    s_wins, d_wins : (n_tiles, rows, block_pairs + 2*inv_margin) — band
            entries extended by the scheme's inverse margin per side.
-    returns x : (rows, n_tiles, 2*block_pairs) merged core samples.
+    returns (even, odd) : (rows, n_tiles * block_pairs) each — the core
+           polyphase samples, for the wrapper to interleave.
     """
-    rows, n_tiles, wlen = s_wins.shape
-    grid = (rows // block_rows, n_tiles)
-    win_spec = pl.BlockSpec((block_rows, 1, wlen), lambda r, t: (r, t, 0))
-    out_spec = pl.BlockSpec(
-        (block_rows, 1, 2 * block_pairs), lambda r, t: (r, t, 0)
-    )
-    out = jax.ShapeDtypeStruct((rows, n_tiles, 2 * block_pairs), s_wins.dtype)
+    n_tiles, rows, wlen = s_wins.shape
+    win_spec, out_spec = _specs(block_rows, wlen, block_pairs)
+    out = jax.ShapeDtypeStruct((rows, n_tiles * block_pairs), s_wins.dtype)
     return pl.pallas_call(
         functools.partial(_inv_kernel, scheme=scheme, mode=mode),
-        grid=grid,
+        grid=(rows // block_rows, n_tiles),
         in_specs=[win_spec, win_spec],
-        out_specs=out_spec,
-        out_shape=out,
+        out_specs=(out_spec, out_spec),
+        out_shape=(out, out),
         interpret=interpret,
     )(s_wins, d_wins)

@@ -80,53 +80,45 @@ def _inv2d_math(
 
 # ---------------------------------------------------------------------------
 # Pallas fused kernel: one grid cell = one image, rows+columns in VMEM.
-# The kernel body IS the band-policy reference math, so the whole-image
-# path supports every registered scheme (windowability not required).
+# The kernel body runs the band-policy reference cascade on the image's
+# polyphase components (split / merged by XLA around the call: Mosaic
+# cannot lay out an in-kernel lane split), so the whole-image path
+# supports every registered scheme (windowability not required).  Refs
+# are in band-code order: ll, hl, lh, hh.
 # ---------------------------------------------------------------------------
 
 
-def _fwd2d_kernel(x_ref, ll_ref, lh_ref, hl_ref, hh_ref, *, scheme: str, mode: str):
-    ll, lh, hl, hh = _fwd2d_math(x_ref[...], mode, scheme)
-    ll_ref[...] = ll
-    lh_ref[...] = lh
-    hl_ref[...] = hl
-    hh_ref[...] = hh
+def _fwd2d_kernel(*refs, scheme, mode: str, h: int, w: int):
+    comp_refs, band_refs = refs[:4], refs[4:]
+    bands = S.lift_fwd_split([r[0] for r in comp_refs], scheme, mode, (w, h))
+    for ref, b in zip(band_refs, bands):
+        ref[0] = b
 
 
-def _inv2d_kernel(ll_ref, lh_ref, hl_ref, hh_ref, x_ref, *, scheme: str, mode: str):
-    x_ref[...] = _inv2d_math(
-        ll_ref[...], lh_ref[...], hl_ref[...], hh_ref[...], mode, scheme
-    )
+def _inv2d_kernel(*refs, scheme, mode: str, h: int, w: int):
+    band_refs, comp_refs = refs[:4], refs[4:]
+    comps = S.lift_inv_split([r[0] for r in band_refs], scheme, mode, (w, h))
+    for ref, c in zip(comp_refs, comps):
+        ref[0] = c
 
 
-def _img_spec(h: int, w: int):
-    return pl.BlockSpec((1, h, w), lambda b: (b, 0, 0))
+def _img_spec(shape):
+    return pl.BlockSpec((1,) + tuple(shape), lambda b: (b, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("scheme", "mode", "interpret"))
 def _fwd2d_pallas(x: Array, scheme: str, mode: str, interpret: bool):
-    bsz, h, w = x.shape
-    h_e, h_o = h - h // 2, h // 2
-    w_e, w_o = w - w // 2, w // 2
-    out_shape = (
-        jax.ShapeDtypeStruct((bsz, h_e, w_e), x.dtype),  # LL
-        jax.ShapeDtypeStruct((bsz, h_o, w_e), x.dtype),  # LH
-        jax.ShapeDtypeStruct((bsz, h_e, w_o), x.dtype),  # HL
-        jax.ShapeDtypeStruct((bsz, h_o, w_o), x.dtype),  # HH
-    )
-    return pl.pallas_call(
-        functools.partial(_fwd2d_kernel, scheme=scheme, mode=mode),
-        grid=(bsz,),
-        in_specs=[_img_spec(h, w)],
-        out_specs=(
-            _img_spec(h_e, w_e),
-            _img_spec(h_o, w_e),
-            _img_spec(h_e, w_o),
-            _img_spec(h_o, w_o),
-        ),
-        out_shape=out_shape,
+    h, w = x.shape[-2:]
+    comps = S.polyphase_split(x, 2)  # forward bands share the comp shapes
+    ll, hl, lh, hh = pl.pallas_call(
+        functools.partial(_fwd2d_kernel, scheme=scheme, mode=mode, h=h, w=w),
+        grid=(x.shape[0],),
+        in_specs=[_img_spec(c.shape[1:]) for c in comps],
+        out_specs=tuple(_img_spec(c.shape[1:]) for c in comps),
+        out_shape=tuple(jax.ShapeDtypeStruct(c.shape, x.dtype) for c in comps),
         interpret=interpret,
-    )(x)
+    )(*comps)
+    return ll, lh, hl, hh
 
 
 @functools.partial(jax.jit, static_argnames=("scheme", "mode", "interpret"))
@@ -134,22 +126,18 @@ def _inv2d_pallas(
     ll: Array, lh: Array, hl: Array, hh: Array,
     scheme: str, mode: str, interpret: bool,
 ):
-    bsz, h_e, w_e = ll.shape
-    h_o, w_o = lh.shape[1], hl.shape[2]
-    h, w = h_e + h_o, w_e + w_o
-    return pl.pallas_call(
-        functools.partial(_inv2d_kernel, scheme=scheme, mode=mode),
-        grid=(bsz,),
-        in_specs=[
-            _img_spec(h_e, w_e),
-            _img_spec(h_o, w_e),
-            _img_spec(h_e, w_o),
-            _img_spec(h_o, w_o),
-        ],
-        out_specs=_img_spec(h, w),
-        out_shape=jax.ShapeDtypeStruct((bsz, h, w), ll.dtype),
+    h = ll.shape[1] + lh.shape[1]
+    w = ll.shape[2] + hl.shape[2]
+    bands = (ll, hl, lh, hh)
+    comps = pl.pallas_call(
+        functools.partial(_inv2d_kernel, scheme=scheme, mode=mode, h=h, w=w),
+        grid=(ll.shape[0],),
+        in_specs=[_img_spec(b.shape[1:]) for b in bands],
+        out_specs=tuple(_img_spec(b.shape[1:]) for b in bands),
+        out_shape=tuple(jax.ShapeDtypeStruct(b.shape, ll.dtype) for b in bands),
         interpret=interpret,
-    )(ll, lh, hl, hh)
+    )(*bands)
+    return S.polyphase_merge(list(comps), (h, w))
 
 
 @functools.partial(jax.jit, static_argnames=("scheme", "mode"))
@@ -305,7 +293,7 @@ def dwt_fwd_2d(
         ll, lh, hl, hh = _fwd2d_xla(x, scheme=sch, mode=mode)
         return Bands2D(ll=ll, lh=lh, hl=hl, hh=hh)
 
-    return _backend.pallas_guard(b, "dwt_fwd_2d", _kernel, _xla)
+    return _backend.pallas_guard(b, "dwt_fwd_2d", x, _kernel, _xla)
 
 
 def dwt_inv_2d(
@@ -340,7 +328,7 @@ def dwt_inv_2d(
         return x.reshape(lead + x.shape[1:])
 
     return _backend.pallas_guard(
-        b, "dwt_inv_2d", _kernel,
+        b, "dwt_inv_2d", bands, _kernel,
         lambda: _inv2d_xla(
             bands.ll, bands.lh, bands.hl, bands.hh, scheme=sch, mode=mode
         ),
@@ -460,7 +448,7 @@ def dwt_fwd_2d_multi(
         ll, details = _fwd2d_multi_xla(x, levels=levels, scheme=sch, mode=mode)
         return Pyramid2D(ll=ll, details=details)
 
-    return _backend.pallas_guard(b, "dwt_fwd_2d_multi", _kernel, _xla)
+    return _backend.pallas_guard(b, "dwt_fwd_2d_multi", x, _kernel, _xla)
 
 
 def dwt_inv_2d_multi(
@@ -511,7 +499,7 @@ def dwt_inv_2d_multi(
         return x.reshape(lead + x.shape[1:])
 
     return _backend.pallas_guard(
-        b, "dwt_inv_2d_multi", _kernel,
+        b, "dwt_inv_2d_multi", pyr, _kernel,
         # _inv2d_xla promotes in-jit; pass the bands through untouched
         lambda: _inv2d_multi_xla(ll, tuple(pyr.details), scheme=sch, mode=mode),
     )

@@ -93,19 +93,28 @@ def _band_dims_3d(d: int, h: int, w: int) -> List[Tuple[int, int, int]]:
 
 
 # ---------------------------------------------------------------------------
-# Whole-volume Pallas kernel: one grid cell = one (D, H, W) volume.
+# Whole-volume Pallas kernel: one grid cell = one (D, H, W) volume.  Like
+# the 2D kernels it runs on the polyphase components (code order, split
+# and merged by XLA around the call), so no in-kernel lane split.
 # ---------------------------------------------------------------------------
 
 
-def _fwd3d_kernel(x_ref, *band_refs, scheme, mode: str):
-    bands = _fwd3d_math(x_ref[...], mode, scheme)
+def _fwd3d_kernel(*refs, scheme, mode: str, dhw):
+    comp_refs, band_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
+    bands = S.lift_fwd_split(
+        [r[0] for r in comp_refs], scheme, mode, tuple(reversed(dhw))
+    )
     for ref, b in zip(band_refs, bands):
-        ref[...] = b
+        ref[0] = b
 
 
-def _inv3d_kernel(*refs, scheme, mode: str):
-    band_refs, x_ref = refs[:-1], refs[-1]
-    x_ref[...] = _inv3d_math([r[...] for r in band_refs], mode, scheme)
+def _inv3d_kernel(*refs, scheme, mode: str, dhw):
+    band_refs, comp_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
+    comps = S.lift_inv_split(
+        [r[0] for r in band_refs], scheme, mode, tuple(reversed(dhw))
+    )
+    for ref, c in zip(comp_refs, comps):
+        ref[0] = c
 
 
 def _vol_spec(d: int, h: int, w: int):
@@ -115,18 +124,17 @@ def _vol_spec(d: int, h: int, w: int):
 @functools.partial(jax.jit, static_argnames=("scheme", "mode", "interpret"))
 def _fwd3d_pallas(x: Array, scheme, mode: str, interpret: bool):
     bsz, d, h, w = x.shape
-    dims = _band_dims_3d(d, h, w)
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((bsz,) + dim, x.dtype) for dim in dims
-    )
+    dims = _band_dims_3d(d, h, w)  # == the polyphase component shapes
     return pl.pallas_call(
-        functools.partial(_fwd3d_kernel, scheme=scheme, mode=mode),
+        functools.partial(_fwd3d_kernel, scheme=scheme, mode=mode, dhw=(d, h, w)),
         grid=(bsz,),
-        in_specs=[_vol_spec(d, h, w)],
+        in_specs=[_vol_spec(*dim) for dim in dims],
         out_specs=tuple(_vol_spec(*dim) for dim in dims),
-        out_shape=out_shape,
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((bsz,) + dim, x.dtype) for dim in dims
+        ),
         interpret=interpret,
-    )(x)
+    )(*S.polyphase_split(x, 3))
 
 
 @functools.partial(jax.jit, static_argnames=("scheme", "mode", "interpret"))
@@ -136,18 +144,24 @@ def _inv3d_pallas(bands: Tuple[Array, ...], scheme, mode: str, interpret: bool):
     h = bands[0].shape[2] + bands[2].shape[2]
     w = bands[0].shape[3] + bands[1].shape[3]
     dims = _band_dims_3d(d, h, w)
-    return pl.pallas_call(
-        functools.partial(_inv3d_kernel, scheme=scheme, mode=mode),
+    comps = pl.pallas_call(
+        functools.partial(_inv3d_kernel, scheme=scheme, mode=mode, dhw=(d, h, w)),
         grid=(bsz,),
         in_specs=[_vol_spec(*dim) for dim in dims],
-        out_specs=_vol_spec(d, h, w),
-        out_shape=jax.ShapeDtypeStruct((bsz, d, h, w), bands[0].dtype),
+        out_specs=tuple(_vol_spec(*dim) for dim in dims),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((bsz,) + dim, bands[0].dtype) for dim in dims
+        ),
         interpret=interpret,
     )(*bands)
+    return S.polyphase_merge(list(comps), (d, h, w))
 
 
 # ---------------------------------------------------------------------------
 # Slab-tiled Pallas kernel: depth-blocked halo windows, planes resident.
+# The plane axes run the exact band-policy cascade per depth slice (the
+# reference's own composition order: -1 then -2); the slab axis runs
+# interior window math on the reflect-extended depth streams.
 # ---------------------------------------------------------------------------
 
 
@@ -155,44 +169,22 @@ def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _fwd_slab_math(win: Array, mode: str, scheme) -> List[Array]:
-    """3D level on a depth-halo'd (TD + 2*halo, H, W) slab window.
-
-    Plane axes run the exact band-policy math per depth slice (the
-    reference's own composition order: -1 then -2); the slab axis runs
-    interior window math on the reflect-extended depth streams.
-    """
-    s_r, d_r = S.lift_fwd_axis(win, scheme, axis=-1, mode=mode)
-    c0, c2 = S.lift_fwd_axis(s_r, scheme, axis=-2, mode=mode)
-    c1, c3 = S.lift_fwd_axis(d_r, scheme, axis=-2, mode=mode)
-    out: List[Array] = [None] * _N_BANDS_3D  # type: ignore[list-item]
-    for code, plane in ((0, c0), (1, c1), (2, c2), (3, c3)):
-        out[code], out[code | 4] = S.lift_fwd_axis_ext(
-            plane, scheme, axis=-3, mode=mode
-        )
-    return out
-
-
-def _inv_slab_math(wins: Sequence[Array], mode: str, scheme) -> Array:
-    """Inverse 3D level from depth-margin-extended band slab windows."""
-    planes = [
-        S.lift_inv_axis_ext(wins[c], wins[c | 4], scheme, axis=-3, mode=mode)
-        for c in range(4)
-    ]
-    s_col = S.lift_inv_axis(planes[0], planes[2], scheme, axis=-2, mode=mode)
-    d_col = S.lift_inv_axis(planes[1], planes[3], scheme, axis=-2, mode=mode)
-    return S.lift_inv_axis(s_col, d_col, scheme, axis=-1, mode=mode)
-
-
-def _fwd_slab_kernel(w_ref, *band_refs, scheme, mode: str):
-    bands = _fwd_slab_math(w_ref[0, 0], mode, scheme)
+def _fwd_slab_kernel(*refs, scheme, mode: str, hw):
+    win_refs, band_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
+    bands = S.lift_fwd_split(
+        [r[0, 0] for r in win_refs], scheme, mode, (hw[1], hw[0], None)
+    )
     for ref, b in zip(band_refs, bands):
         ref[0] = b
 
 
-def _inv_slab_kernel(*refs, scheme, mode: str):
-    band_refs, x_ref = refs[:-1], refs[-1]
-    x_ref[0] = _inv_slab_math([r[0, 0] for r in band_refs], mode, scheme)
+def _inv_slab_kernel(*refs, scheme, mode: str, hw):
+    band_refs, comp_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
+    comps = S.lift_inv_split(
+        [r[0, 0] for r in band_refs], scheme, mode, (hw[1], hw[0], None)
+    )
+    for ref, c in zip(comp_refs, comps):
+        ref[0] = c
 
 
 def _slab_win_spec(wd: int, h: int, w: int):
@@ -224,6 +216,7 @@ def fwd3d_slab(
     """
     sch = S.get_scheme(scheme)
     halo = sch.halo
+    m = sch.fwd_margin
     bsz, d, h, w = x.shape
     dims = _band_dims_3d(d, h, w)
     d_e = d - d // 2
@@ -235,19 +228,26 @@ def fwd3d_slab(
             for t in range(n_slabs)
         ]
     )
-    windows = _depth_windows(x, rows)
-    out_shape = tuple(
-        jax.ShapeDtypeStruct((bsz, n_slabs * bd) + dim[1:], x.dtype)
-        for dim in dims
-    )
+    # windows start on an even slice (halo = 2*fwd_margin): the stride-2
+    # rows of the map are the depth-even / depth-odd slices
+    planes = S.polyphase_split(x, 2)
+    wins = [
+        _depth_windows(planes[code & 3], rows[:, (code >> 2) & 1 :: 2])
+        for code in range(_N_BANDS_3D)
+    ]
     bands = pl.pallas_call(
-        functools.partial(_fwd_slab_kernel, scheme=sch, mode=mode),
+        functools.partial(_fwd_slab_kernel, scheme=sch, mode=mode, hw=(h, w)),
         grid=(bsz, n_slabs),
-        in_specs=[_slab_win_spec(td + 2 * halo, h, w)],
+        in_specs=[
+            _slab_win_spec(bd + 2 * m, *dim[1:]) for dim in dims
+        ],
         out_specs=tuple(_slab_out_spec(bd, *dim[1:]) for dim in dims),
-        out_shape=out_shape,
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((bsz, n_slabs * bd) + dim[1:], x.dtype)
+            for dim in dims
+        ),
         interpret=interpret,
-    )(windows)
+    )(*wins)
     return tuple(b[:, : dim[0]] for b, dim in zip(bands, dims))
 
 
@@ -285,17 +285,21 @@ def inv3d_slab(
         for code, b in enumerate(bands)
     )
     dims = _band_dims_3d(d, h, w)
-    x = pl.pallas_call(
-        functools.partial(_inv_slab_kernel, scheme=sch, mode=mode),
+    comps = pl.pallas_call(
+        functools.partial(_inv_slab_kernel, scheme=sch, mode=mode, hw=(h, w)),
         grid=(bsz, n_slabs),
         in_specs=[
             _slab_win_spec(me + 2 * m, *dims[code][1:])
             for code in range(_N_BANDS_3D)
         ],
-        out_specs=_slab_out_spec(td, h, w),
-        out_shape=jax.ShapeDtypeStruct((bsz, n_slabs * td, h, w), bands[0].dtype),
+        out_specs=tuple(_slab_out_spec(me, *dim[1:]) for dim in dims),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((bsz, n_slabs * me) + dim[1:], bands[0].dtype)
+            for dim in dims
+        ),
         interpret=interpret,
     )(*wins)
+    x = S.polyphase_merge(list(comps), (n_slabs * td, h, w))
     return x[:, :d]
 
 
@@ -588,7 +592,7 @@ def dwt_fwd_nd(
         )
         return PyramidND(approx=approx, details=details)
 
-    return _backend.pallas_guard(b, "dwt_fwd_nd", _kernel, _xla)
+    return _backend.pallas_guard(b, "dwt_fwd_nd", x, _kernel, _xla)
 
 
 def dwt_inv_nd(
@@ -656,7 +660,7 @@ def dwt_inv_nd(
         return x.reshape(lead + x.shape[1:])
 
     return _backend.pallas_guard(
-        b, "dwt_inv_nd", _kernel,
+        b, "dwt_inv_nd", pyr, _kernel,
         lambda: _inv3d_multi_xla(
             pyr.approx, tuple(pyr.details), scheme=sch, mode=mode
         ),

@@ -100,25 +100,31 @@ def _fwd_level(
     wlen = 2 * block_pairs + 2 * halo
     # trace-time window maps: tile t covers core pairs [t*bp, (t+1)*bp),
     # i.e. samples [2*t*bp - halo, ...+wlen) reflected into range — every
-    # window entry is an exact whole-point extension value.
+    # window entry is an exact whole-point extension value.  Windows start
+    # on an even sample (halo = 2*fwd_margin), so the stride-2 columns of
+    # the map are the even / odd polyphase streams the kernel takes.
     idx = np.stack(
         [
             S.reflect_indices(2 * t * block_pairs - halo, wlen, n)
             for t in range(n_tiles)
         ]
     )
-    wins = _pad_rows(xf, rows_pad)[:, idx]  # (rows_pad, n_tiles, wlen)
-    s_t, d_t = _k.lift_fwd_windows(
-        wins,
+    xp = _pad_rows(xf, rows_pad)
+    s, d = _k.lift_fwd_windows(
+        _tile_major(xp, idx[:, 0::2]),
+        _tile_major(xp, idx[:, 1::2]),
         scheme=sch,
         mode=mode,
         block_rows=block_rows,
         block_pairs=block_pairs,
         interpret=interpret,
     )
-    s = s_t.reshape(rows_pad, pairs_pad)[:rows, :n_e]
-    d = d_t.reshape(rows_pad, pairs_pad)[:rows, :n_o]
-    return s, d
+    return s[:rows, :n_e], d[:rows, :n_o]
+
+
+def _tile_major(a: jax.Array, idx: np.ndarray) -> jax.Array:
+    """(rows, n) gathered through (n_tiles, wlen) maps -> (n_tiles, rows, wlen)."""
+    return jnp.transpose(a[:, idx], (1, 0, 2))
 
 
 def _inv_level(
@@ -150,18 +156,16 @@ def _inv_level(
             for t in range(n_tiles)
         ]
     )
-    s_wins = _pad_rows(sf, rows_pad)[:, idx_s]
-    d_wins = _pad_rows(df, rows_pad)[:, idx_d]
-    x_t = _k.lift_inv_windows(
-        s_wins,
-        d_wins,
+    even, odd = _k.lift_inv_windows(
+        _tile_major(_pad_rows(sf, rows_pad), idx_s),
+        _tile_major(_pad_rows(df, rows_pad), idx_d),
         scheme=sch,
         mode=mode,
         block_rows=block_rows,
         block_pairs=block_pairs,
         interpret=interpret,
     )
-    return x_t.reshape(rows_pad, 2 * pairs_pad)[:rows, :n]
+    return S.merge_axis(even, odd, -1, 2 * pairs_pad)[:rows, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +295,7 @@ def dwt_fwd_1d(
         )
     b = _backend.resolve(backend)
     return _backend.pallas_guard(
-        b, "dwt_fwd_1d",
+        b, "dwt_fwd_1d", x,
         lambda: _fwd_1d_kernel(
             x, scheme=sch, mode=mode, interpret=_backend.interpret_flag(b)
         ),
@@ -321,7 +325,7 @@ def dwt_inv_1d(
         )
     b = _backend.resolve(backend)
     return _backend.pallas_guard(
-        b, "dwt_inv_1d",
+        b, "dwt_inv_1d", (s, d),
         lambda: _inv_1d_kernel(
             s, d, scheme=sch, mode=mode, interpret=_backend.interpret_flag(b)
         ),
@@ -362,7 +366,7 @@ def dwt_fwd(
         )
     b = _backend.resolve(backend)
     approx, details = _backend.pallas_guard(
-        b, "dwt_fwd",
+        b, "dwt_fwd", x,
         lambda: _fwd_multi_kernel(
             x,
             levels=levels,
@@ -404,7 +408,7 @@ def dwt_inv(
         n = n + d.shape[-1]
     b = _backend.resolve(backend)
     return _backend.pallas_guard(
-        b, "dwt_inv",
+        b, "dwt_inv", pyr,
         lambda: _inv_multi_kernel(
             pyr.approx,
             tuple(pyr.details),

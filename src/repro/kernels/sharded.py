@@ -118,19 +118,6 @@ def _watchdogged(thunk, label: str, timeout_s: Optional[float]):
     return result[0]
 
 
-def _shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """Full-manual shard_map across jax versions (see train_step.py)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    # check_rep=False: the halo ppermutes over one axis confuse the 0.4.x
-    # replication checker when the mesh has additional (replicated) axes
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def _scheme_shardable(sch: S.LiftingScheme) -> bool:
     # the column stage's exchanged-halo interior math must reproduce the
     # band policy: reflection-commuting steps, or no halo at all (haar —
@@ -325,7 +312,9 @@ def _fwd_sharded_fn(
             details.append((lh, hl, hh))
         return Pyramid2D(ll=ll, details=tuple(reversed(details)))
 
-    return jax.jit(_shard_map_compat(local_fwd, mesh, (spec,), out_specs))
+    return jax.jit(
+        jax.shard_map(local_fwd, mesh=mesh, in_specs=(spec,), out_specs=out_specs)
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -346,7 +335,9 @@ def _inv_sharded_fn(
             ll = _inv_level_local(ll, lh, hl, hh, scheme, mode, axis, n)
         return ll
 
-    return jax.jit(_shard_map_compat(local_inv, mesh, in_specs, spec))
+    return jax.jit(
+        jax.shard_map(local_inv, mesh=mesh, in_specs=in_specs, out_specs=spec)
+    )
 
 
 def dwt_fwd_2d_sharded(

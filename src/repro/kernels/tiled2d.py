@@ -29,12 +29,15 @@ with no boundary special cases inside the kernel:
   inverse : band windows (TH/2 + 2*m, TW/2 + 2*m), m = scheme.inv_margin
             -> image tile (TH, TW)
 
-``schemes.lift_fwd_axis_ext`` / ``lift_inv_axis_ext`` implement that
-interior math along one axis of an already-extended array; they are pure
-slice/concat + the scheme's add/shift arithmetic, so the SAME functions
-run inside the Pallas kernels, under plain XLA, and as the local compute
-of the ``shard_map`` transform (``kernels/sharded.py``), which swaps the
-reflect halo for ``ppermute``-exchanged neighbor rows.
+Mosaic cannot lay out an in-kernel even/odd split of the lane axis, so
+the window gather itself splits: each window arrives as its four
+polyphase components (the index maps taken at stride 2 — windows start
+on an even sample because the halo is ``2 * fwd_margin``), the kernel
+runs ``schemes.lift_fwd_split`` / ``lift_inv_split`` on them, and the
+inverse returns the tile's four polyphase components for the wrapper to
+interleave.  The default 256-sample core tile makes every band tile a
+(128, 128) block — (8, 128)-aligned, as Mosaic requires of a block that
+is not the whole array.
 
 Tile selection (``backend.pick_tile``) derives from the queried device
 memory budget and the scheme's halo; ``REPRO_DWT_TILE`` overrides.  See
@@ -55,34 +58,10 @@ from repro.core import schemes as S
 Array = jax.Array
 
 
-# back-compat aliases (cdf53 instance of the generic interior math; the
-# tier-1 identity test drives them directly)
+# back-compat alias (cdf53 instance of the generic interior math; the
+# tier-1 identity test drives it directly)
 def _fwd_axis_ext(x: Array, axis: int, mode: str) -> Tuple[Array, Array]:
     return S.lift_fwd_axis_ext(x, "cdf53", axis=axis, mode=mode)
-
-
-def _inv_axis_ext(s_ext: Array, d_ext: Array, axis: int, mode: str) -> Array:
-    return S.lift_inv_axis_ext(s_ext, d_ext, "cdf53", axis=axis, mode=mode)
-
-
-def fwd_window_math(
-    w: Array, mode: str, scheme: str = "cdf53"
-) -> Tuple[Array, Array, Array, Array]:
-    """Full 2D level on a halo'd (..., TH+2h, TW+2h) window: rows, cols."""
-    s_r, d_r = S.lift_fwd_axis_ext(w, scheme, axis=-1, mode=mode)
-    ll, lh = S.lift_fwd_axis_ext(s_r, scheme, axis=-2, mode=mode)
-    hl, hh = S.lift_fwd_axis_ext(d_r, scheme, axis=-2, mode=mode)
-    return ll, lh, hl, hh
-
-
-def inv_window_math(
-    llw: Array, lhw: Array, hlw: Array, hhw: Array, mode: str,
-    scheme: str = "cdf53",
-) -> Array:
-    """Inverse 2D level on margin-extended (..., P+2m, Q+2m) band windows."""
-    s_col = S.lift_inv_axis_ext(llw, lhw, scheme, axis=-2, mode=mode)
-    d_col = S.lift_inv_axis_ext(hlw, hhw, scheme, axis=-2, mode=mode)
-    return S.lift_inv_axis_ext(s_col, d_col, scheme, axis=-1, mode=mode)
 
 
 # ---------------------------------------------------------------------------
@@ -107,22 +86,27 @@ def _gather2d(x: Array, rows: np.ndarray, cols: np.ndarray) -> Array:
 
 # ---------------------------------------------------------------------------
 # Pallas kernels: one grid cell = one halo'd window, resident in VMEM.
+# Refs and results are in band-code order (bit 0 = odd / highpass along
+# the width, bit 1 along the height): ll, hl, lh, hh.
 # ---------------------------------------------------------------------------
 
 
-def _fwd_tile_kernel(w_ref, ll_ref, lh_ref, hl_ref, hh_ref, *, scheme: str, mode: str):
-    ll, lh, hl, hh = fwd_window_math(w_ref[0, 0, 0], mode, scheme)
-    ll_ref[0] = ll
-    lh_ref[0] = lh
-    hl_ref[0] = hl
-    hh_ref[0] = hh
-
-
-def _inv_tile_kernel(ll_ref, lh_ref, hl_ref, hh_ref, x_ref, *, scheme: str, mode: str):
-    x_ref[0] = inv_window_math(
-        ll_ref[0, 0, 0], lh_ref[0, 0, 0], hl_ref[0, 0, 0], hh_ref[0, 0, 0],
-        mode, scheme,
+def _fwd_tile_kernel(*refs, scheme, mode: str):
+    win_refs, band_refs = refs[:4], refs[4:]
+    bands = S.lift_fwd_split(
+        [r[0, 0, 0] for r in win_refs], scheme, mode, (None, None)
     )
+    for ref, b in zip(band_refs, bands):
+        ref[0] = b
+
+
+def _inv_tile_kernel(*refs, scheme, mode: str):
+    band_refs, comp_refs = refs[:4], refs[4:]
+    comps = S.lift_inv_split(
+        [r[0, 0, 0] for r in band_refs], scheme, mode, (None, None)
+    )
+    for ref, c in zip(comp_refs, comps):
+        ref[0] = c
 
 
 def _win_spec(wh: int, ww: int):
@@ -151,6 +135,7 @@ def fwd2d_tiled(
     """
     sch = S.get_scheme(scheme)
     halo = sch.halo
+    m = sch.fwd_margin
     bsz, h, w = x.shape
     h_e, w_e = h - h // 2, w - w // 2
     h_o, w_o = h // 2, w // 2
@@ -159,16 +144,19 @@ def fwd2d_tiled(
     n_tw = _ceil_to(w_e, bw) // bw
     rows = _win_rows(n_th, th, halo, lambda s, c: S.reflect_indices(s, c, h))
     cols = _win_rows(n_tw, tw, halo, lambda s, c: S.reflect_indices(s, c, w))
-    windows = _gather2d(x, rows, cols)
+    comps = [
+        _gather2d(x, rows[:, (code >> 1) & 1 :: 2], cols[:, code & 1 :: 2])
+        for code in range(4)
+    ]
     out = jax.ShapeDtypeStruct((bsz, n_th * bh, n_tw * bw), x.dtype)
-    ll, lh, hl, hh = pl.pallas_call(
+    ll, hl, lh, hh = pl.pallas_call(
         functools.partial(_fwd_tile_kernel, scheme=sch, mode=mode),
         grid=(bsz, n_th, n_tw),
-        in_specs=[_win_spec(th + 2 * halo, tw + 2 * halo)],
+        in_specs=[_win_spec(bh + 2 * m, bw + 2 * m)] * 4,
         out_specs=(_out_spec(bh, bw),) * 4,
         out_shape=(out,) * 4,
         interpret=interpret,
-    )(windows)
+    )(*comps)
     return (
         ll[:, :h_e, :w_e],
         lh[:, :h_o, :w_e],
@@ -204,16 +192,20 @@ def inv2d_tiled(
     r_d = _win_rows(n_th, me, m, lambda s, c: S.reflect_entries(s, c, 1, h))
     c_s = _win_rows(n_tw, mo, m, lambda s, c: S.reflect_entries(s, c, 0, w))
     c_d = _win_rows(n_tw, mo, m, lambda s, c: S.reflect_entries(s, c, 1, w))
-    llw = _gather2d(ll, r_s, c_s)
-    lhw = _gather2d(lh, r_d, c_s)
-    hlw = _gather2d(hl, r_s, c_d)
-    hhw = _gather2d(hh, r_d, c_d)
-    x = pl.pallas_call(
+    wins = (
+        _gather2d(ll, r_s, c_s),
+        _gather2d(hl, r_s, c_d),
+        _gather2d(lh, r_d, c_s),
+        _gather2d(hh, r_d, c_d),
+    )
+    comp = jax.ShapeDtypeStruct((bsz, n_th * me, n_tw * mo), ll.dtype)
+    comps = pl.pallas_call(
         functools.partial(_inv_tile_kernel, scheme=sch, mode=mode),
         grid=(bsz, n_th, n_tw),
         in_specs=[_win_spec(me + 2 * m, mo + 2 * m)] * 4,
-        out_specs=_out_spec(th, tw),
-        out_shape=jax.ShapeDtypeStruct((bsz, n_th * th, n_tw * tw), ll.dtype),
+        out_specs=(_out_spec(me, mo),) * 4,
+        out_shape=(comp,) * 4,
         interpret=interpret,
-    )(llw, lhw, hlw, hhw)
+    )(*wins)
+    x = S.polyphase_merge(list(comps), (n_th * th, n_tw * tw))
     return x[:, :h, :w]

@@ -237,21 +237,12 @@ def probe_cfg(cfg: ArchConfig, cell: ShapeCell, n_layers: int) -> ArchConfig:
     )
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions (older jax returns a
-    one-element list of dicts, newer jax the dict itself)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _cost_of(cfg: ArchConfig, cell: ShapeCell, mesh, multi_pod: bool, chips: int):
     jitted, args, _ = build_cell(cfg, cell, mesh, multi_pod)
     with mesh:
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = RL.parse_collectives(compiled.as_text(), chips)
     return (
         float(cost.get("flops", 0.0)),
@@ -356,7 +347,7 @@ def run_cell(
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = RL.parse_collectives(hlo, chips)
         model_flops = RL.model_flops_for(

@@ -13,16 +13,10 @@ from jax.sharding import Mesh
 
 
 def make_mesh_compat(shape, axes) -> Mesh:
-    """jax.make_mesh across jax versions.
-
-    ``axis_types`` (jax.sharding.AxisType) only exists on newer jax; older
-    releases (<= 0.4.x) default every axis to Auto, which is exactly what
-    we want — so pass it only when available.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -263,12 +263,14 @@ class WaveletServeEngine:
         rode a failed attempt must never be resubmitted.
         """
         attempts = self.max_retries + 1
+        # fetching the executable compiles it on a cache miss; a lowering
+        # or compile failure is deterministic, so it propagates from here
+        # on the first attempt instead of being retried as transient
+        fn = self.executor.executable(key, self.mesh)
         for attempt in range(attempts):
             try:
                 inject.check("serve.transform")
-                out = self.executor.transform(
-                    jnp.asarray(batch_np), key, self.mesh
-                )
+                out = self.executor.run(fn, jnp.asarray(batch_np), key)
             except Exception as e:  # noqa: BLE001 - transient device faults
                 if attempt + 1 >= attempts:
                     obs.counter("serve.retries_exhausted").inc()
@@ -393,12 +395,13 @@ class WaveletServeEngine:
                       n=len(active)):
             try:
                 pyr = self._transform_with_retry(batch, key)
-            except RetryExhaustedError:
-                # no live request is lost: the batch goes back to its queue
-                # head while the error reaches the caller.  Requests whose
-                # deadline passed DURING the failed attempts are expired here
-                # — a re-queued batch must not serve already-overdue work —
-                # and delivered (typed error attached) by the next step()
+            except Exception:
+                # no live request is lost (retry exhaustion or a compile
+                # failure): the batch goes back to its queue head while the
+                # error reaches the caller.  Requests whose deadline passed
+                # DURING the failed attempts are expired here — a re-queued
+                # batch must not serve already-overdue work — and delivered
+                # (typed error attached) by the next step()
                 expired, live = self.scheduler.expire_batch(active)
                 self._expired_out.extend(expired)
                 self.scheduler.requeue_front(bucket, live)

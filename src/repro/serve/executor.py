@@ -36,6 +36,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import backend as _backend
@@ -126,10 +127,15 @@ class TransformExecutor:
         # device buffer is dead after the call: donate it where the
         # platform supports donation (CPU does not and would warn)
         donate = () if _backend.platform() == "cpu" else (0,)
-        return jax.jit(transform, donate_argnums=donate)
+        # compiled ahead of time from the static batch shape, so a
+        # lowering or compile failure raises here, at build time — the
+        # engine never mistakes one for a transient fault of a run
+        batch = jax.ShapeDtypeStruct((key.batch_slots,) + key.bucket, jnp.int32)
+        return jax.jit(transform, donate_argnums=donate).lower(batch).compile()
 
     def executable(self, key: ExecKey, mesh: Optional[Any] = None) -> Callable:
-        """The cached executable for ``key`` (built on first use)."""
+        """The cached executable for ``key`` (built and compiled on first
+        use; compile errors propagate from here)."""
         fn = self._cache.get(key)
         if fn is None:
             self.misses += 1
@@ -142,29 +148,32 @@ class TransformExecutor:
         obs.gauge("serve.executor_hit_rate").set(self.hit_rate())
         return fn
 
-    def transform(self, batch, key: ExecKey, mesh: Optional[Any] = None):
-        """Run the batch through the key's compiled executable.
+    def run(self, fn: Callable, batch, key: ExecKey):
+        """Run ``batch`` through an executable :meth:`executable` returned.
 
-        The span measures HOST dispatch wall time (async dispatch —
-        no added sync); the executable itself is jit-cached, so the
-        span also brackets compile time on a cache miss.
+        The span measures HOST dispatch wall time (async dispatch — no
+        added sync).
         """
-        fn = self.executable(key, mesh)
         bucket = "x".join(str(s) for s in key.bucket)
         with obs.span("serve.transform", subsystem="serve", bucket=bucket):
             return fn(batch)
 
     def warmup(self, keys, mesh: Optional[Any] = None) -> int:
-        """Pre-build executables for ``keys``; returns how many were new.
+        """Build and compile executables for ``keys``; returns how many
+        were new.
 
-        Building compiles lazily on first data anyway; warmup exists so
-        an engine can pay every compile before taking traffic.
+        Every compile is paid here, before traffic, so it counts as
+        set-up time and the first request of a bucket runs a compiled
+        program.  The sharded route's callable compiles its inner jit on
+        its first run, so warmup runs it once on a zero batch.
         """
         new = 0
         for key in keys:
-            if key not in self._cache:
-                self.misses += 1
-                obs.counter("serve.executor_cache", outcome="miss").inc()
-                self._cache[key] = self._build(key, mesh)
-                new += 1
+            if key in self._cache:
+                continue
+            fn = self.executable(key, mesh)
+            if key.mesh_axes is not None:
+                zeros = jnp.zeros((key.batch_slots,) + key.bucket, jnp.int32)
+                jax.block_until_ready(fn(zeros))
+            new += 1
         return new

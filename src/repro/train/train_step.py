@@ -23,30 +23,6 @@ from repro.train.grad_compress import WaveletSyncConfig, pod_sync_tree
 PyTree = Any
 
 
-def _shard_map_manual(f, mesh, in_specs, out_specs, manual_axes):
-    """Partial-manual shard_map across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=...)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` where the
-    complement set is passed as ``auto=``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=set(manual_axes),
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=True, auto=auto,
-    )
-
-
 def _split_microbatches(batch: PyTree, n_micro: int) -> PyTree:
     """(B, ...) -> (n_micro, B/n_micro, ...) for scan."""
 
@@ -160,12 +136,12 @@ def make_wavelet_train_step(
         return repod(new_params), new_opt_p, repod(err_fb), out_metrics
 
     opt_spec = optim.AdamWState(step=P(), m=P("pod"), v=P("pod"))
-    step = _shard_map_manual(
+    step = jax.shard_map(
         pod_local_step,
-        mesh,
+        mesh=mesh,
         in_specs=(P("pod"), opt_spec, P("pod"), P("pod")),
         out_specs=(P("pod"), opt_spec, P("pod"), P()),
-        manual_axes={"pod"},
+        axis_names={"pod"},
     )
     return jax.jit(step)  # shard_map requires jit (no eager closed_call)
 

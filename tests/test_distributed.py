@@ -27,12 +27,6 @@ def _run(body: str) -> str:
     return proc.stdout
 
 
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="partial-manual shard_map (axis_names=) needs jax>=0.5; the "
-    "0.4.x experimental fallback hits an XLA partitioner check "
-    "(IsManualSubgroup) on the full train step",
-)
 @pytest.mark.slow
 @pytest.mark.sharded
 def test_wavelet_multipod_step_matches_baseline():

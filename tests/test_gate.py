@@ -87,7 +87,7 @@ def _good_bench() -> dict:
                 "bit-flip": "recovered",
                 "truncation": "typed-error",
                 "save-crash": "previous-intact",
-                "pallas-failure": "degraded",
+                "pallas-failure": "typed-error",
                 "stuck-neighbor": "typed-error",
                 "deadline-miss": "typed-error",
             },

@@ -58,7 +58,7 @@ def _rand(shape, lo, hi, seed=0, dtype=np.int32):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(
     scheme=st.sampled_from(SCHEMES),
     mode=st.sampled_from(MODES),
@@ -85,7 +85,7 @@ def test_trace_forward_bounds_actual_bands_1d(scheme, mode, levels, mag_bits, se
         assert iv.lo <= b.min() and b.max() <= iv.hi
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(
     scheme=st.sampled_from(SCHEMES),
     mode=st.sampled_from(MODES),
@@ -377,7 +377,7 @@ def test_extreme_int32_every_engine(val, name, roundtrip, shape):
         roundtrip(x, checked=True)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(
     scheme=st.sampled_from(SCHEMES),
     val=st.sampled_from([I32.min, I32.max, I32.min + 1, I32.max - 1, 2**30]),

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import kernels as K
+from repro import obs
 from repro.codec import (
     CodecError,
     CorruptBandError,
@@ -32,7 +33,6 @@ from repro.codec import (
 from repro.codec import stream as wzrs
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.ckpt.ft import StragglerWatchdog
-from repro.kernels import backend as B
 from repro.resilience import (
     CheckpointIntegrityError,
     DeadlineExceededError,
@@ -365,36 +365,41 @@ def test_wzrice_leaf_unhealable_raises_checksum_ioerror(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Kernel dispatch: pallas failure degrades to the bit-exact XLA path.
+# Kernel dispatch: a pallas failure raises — no fallback hides the device.
 # ---------------------------------------------------------------------------
 
 
 def test_pallas_failure_degrades_bit_exact():
+    """An armed kernel fault surfaces as the typed error on every call —
+    never a silent recompute on the XLA reference — and records a
+    kernels FaultEvent naming the site; the next clean call is exact."""
     rng = np.random.default_rng(CHAOS_SEED)
     x = rng.integers(-300, 300, (3, 24, 40), dtype=np.int32)
     want = K.dwt_fwd_2d_multi(x, levels=2, backend="xla")
-    B._warned_degrades.clear()
+    obs.events.reset()
     with inject.armed("kernels.pallas", times=None):
-        with pytest.warns(B.BackendDegradeWarning, match="kernel path failed"):
-            got = K.dwt_fwd_2d_multi(x, levels=2, backend="interpret")
+        for _ in range(2):
+            with pytest.raises(InjectedFault):
+                K.dwt_fwd_2d_multi(x, levels=2, backend="interpret")
+    faults = obs.events.query(obs.FaultEvent, subsystem="kernels")
+    assert [e.site for e in faults] == ["dwt_fwd_2d_multi"] * 2
+    got = K.dwt_fwd_2d_multi(x, levels=2, backend="interpret")
     assert _pyramids_equal(got, want)
-    # the degrade dedups: a second identical failure stays silent
-    with inject.armed("kernels.pallas", times=None):
-        got2 = K.dwt_fwd_2d_multi(x, levels=2, backend="interpret")
-    assert _pyramids_equal(got2, want)
 
 
 def test_pallas_failure_1d_and_nd_guarded():
     rng = np.random.default_rng(CHAOS_SEED)
     x1 = rng.integers(-100, 100, (64,), dtype=np.int32)
     x3 = rng.integers(-100, 100, (8, 8, 8), dtype=np.int32)
-    B._warned_degrades.clear()
     with inject.armed("kernels.pallas", times=None):
-        p1 = K.dwt_fwd(x1, levels=2, backend="interpret")
-        p3 = K.dwt_fwd_nd(x3, levels=1, backend="interpret", ndim=3)
-    assert _pyramids_equal(p1, K.dwt_fwd(x1, levels=2, backend="xla"))
+        with pytest.raises(InjectedFault):
+            K.dwt_fwd(x1, levels=2, backend="interpret")
+        with pytest.raises(InjectedFault):
+            K.dwt_fwd_nd(x3, levels=1, backend="interpret", ndim=3)
+        # the XLA path never enters the kernel guard's fault site
+        p1 = K.dwt_fwd(x1, levels=2, backend="xla")
     assert _pyramids_equal(
-        p3, K.dwt_fwd_nd(x3, levels=1, backend="xla", ndim=3)
+        p1, K.dwt_fwd(x1, levels=2, backend="interpret")
     )
 
 

@@ -158,6 +158,22 @@ def test_executable_cache_compiles_once_per_bucket():
     assert eng.executor.traces == 2, "cached executable retraced"
 
 
+def test_warmup_compiles_every_bucket_before_traffic():
+    """Warm-up traces and compiles each bucket's executable from its
+    static batch shape, so serving the first requests traces nothing."""
+    eng = WaveletServeEngine(
+        buckets=[(16, 16), (32, 32)], batch_slots=2, levels=1
+    )
+    eng.warmup()
+    assert eng.executor.traces == 2
+    done = eng.run([
+        TransformRequest(uid=i, image=_image(i, (16, 16) if i % 2 else (32, 32)))
+        for i in range(4)
+    ])
+    assert all(r.done for r in done)
+    assert eng.executor.traces == 2, "a request paid a trace after warm-up"
+
+
 def test_executor_key_isolation():
     """Distinct (scheme, levels) settings get distinct executables; the
     same key built twice is a cache bug, not a new compile."""

@@ -155,10 +155,6 @@ def test_spatial_2d_pod_sync_converges_to_mean():
         from jax.sharding import PartitionSpec as P
         from repro.train.grad_compress import WaveletSyncConfig, pod_sync_tree
         from repro.launch.mesh import make_mesh_compat
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            shard_map = jax.shard_map
         mesh = make_mesh_compat((2,), ("pod",))
         rng = np.random.default_rng(5)
         grads = {"w": jnp.asarray(rng.normal(size=(2, 64, 96)), jnp.float32),
@@ -169,9 +165,9 @@ def test_spatial_2d_pod_sync_converges_to_mean():
                "v": jnp.zeros((8000,), jnp.float32)}
         cfg = WaveletSyncConfig(levels=2, codec="bands", n_pods=2,
                                 min_size=256, spatial_2d=True)
-        f = shard_map(lambda g, e: pod_sync_tree(g, e, cfg, axis_name="pod"),
-                      mesh=mesh, in_specs=(P("pod"), P()),
-                      out_specs=(P(), P()), check_rep=False)
+        f = jax.shard_map(lambda g, e: pod_sync_tree(g, e, cfg, axis_name="pod"),
+                          mesh=mesh, in_specs=(P("pod"), P()),
+                          out_specs=(P(), P()), check_vma=False)
         synced, new_err = jax.jit(f)(grads, err)
         for k, g in grads.items():
             want = np.mean(np.asarray(g), axis=0)
@@ -199,10 +195,6 @@ def test_spatial_3d_pod_sync_converges_to_mean():
         from jax.sharding import PartitionSpec as P
         from repro.train.grad_compress import WaveletSyncConfig, pod_sync_tree
         from repro.launch.mesh import make_mesh_compat
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            shard_map = jax.shard_map
         mesh = make_mesh_compat((2,), ("pod",))
         rng = np.random.default_rng(7)
         grads = {"act": jnp.asarray(rng.normal(size=(2, 6, 16, 24)), jnp.float32),
@@ -213,9 +205,9 @@ def test_spatial_3d_pod_sync_converges_to_mean():
                "v": jnp.zeros((8000,), jnp.float32)}
         cfg = WaveletSyncConfig(levels=2, codec="bands", n_pods=2,
                                 min_size=256, spatial_3d=True, spatial_2d=True)
-        f = shard_map(lambda g, e: pod_sync_tree(g, e, cfg, axis_name="pod"),
-                      mesh=mesh, in_specs=(P("pod"), P()),
-                      out_specs=(P(), P()), check_rep=False)
+        f = jax.shard_map(lambda g, e: pod_sync_tree(g, e, cfg, axis_name="pod"),
+                          mesh=mesh, in_specs=(P("pod"), P()),
+                          out_specs=(P(), P()), check_vma=False)
         synced, new_err = jax.jit(f)(grads, err)
         for k, g in grads.items():
             want = np.mean(np.asarray(g), axis=0)

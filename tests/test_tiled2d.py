@@ -26,7 +26,7 @@ def _assert_bands_equal(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     h=st.integers(min_value=3, max_value=40),
     w=st.integers(min_value=3, max_value=40),
@@ -41,7 +41,7 @@ def test_fwd_tiled_matches_ref_property(h, w, th, tw, mode):
     _assert_bands_equal((ll, lh, hl, hh), (want.ll, want.lh, want.hl, want.hh))
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     h=st.integers(min_value=3, max_value=40),
     w=st.integers(min_value=3, max_value=40),
