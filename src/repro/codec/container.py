@@ -64,7 +64,6 @@ path and the parity reconstruction all lean on.
 from __future__ import annotations
 
 import struct
-import time
 import zlib
 from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -243,16 +242,6 @@ def _xor_parity(blobs: Sequence[bytes], plen: int) -> bytes:
 # ---------------------------------------------------------------------------
 
 
-def _raw_nbytes(pyr: Any) -> int:
-    """Uncompressed band bytes, from shape/dtype metadata only (never
-    touches band data — no device sync)."""
-    return sum(
-        int(leaf.size) * np.dtype(leaf.dtype).itemsize
-        for leaf in jax.tree_util.tree_leaves(pyr)
-        if hasattr(leaf, "size") and hasattr(leaf, "dtype")
-    )
-
-
 def encode_pyramid(
     pyr: Any,
     scheme: str = "cdf53",
@@ -267,24 +256,18 @@ def encode_pyramid(
 ) -> bytes:
     """Serialize an integer wavelet pyramid (see :func:`_encode_impl`).
 
-    Instrumented entry point: records encode duration, coded bytes, and
-    the raw/coded compression ratio in the process-wide obs registry
-    (``codec.encode_*``) around the actual encoder.
+    Instrumented entry point: the ``codec.encode_pyramid`` span, whose
+    children are the per-band ``codec.encode_band`` spans, so its self
+    time is the container assembly (band fetch, headers, CRCs); coded
+    bytes count into ``codec.encode_bytes``.
     """
-    t0 = time.perf_counter()
     with obs.span("codec.encode_pyramid", subsystem="codec"):
         out = _encode_impl(
             pyr, scheme, mode, ndim=ndim, backend=backend,
             checksum=checksum, parity=parity, version=version,
             checked=checked,
         )
-    dur_ms = (time.perf_counter() - t0) * 1e3
-    obs.counter("codec.encode_calls").inc()
     obs.counter("codec.encode_bytes").inc(len(out))
-    obs.histogram("codec.encode_ms").observe(dur_ms)
-    raw = _raw_nbytes(pyr)
-    if raw and out:
-        obs.gauge("codec.compression_ratio").set(raw / len(out))
     return out
 
 
@@ -335,6 +318,8 @@ def _encode_impl(
         raise ValueError(f"mode must be one of {sorted(_MODES)}, got {mode!r}")
     nd, lead, shape = _infer_geometry(pyr, kind, ndim)
     levels = len(pyr.details)
+    with obs.waiting():  # the transform that makes the bands may still run
+        jax.block_until_ready(pyr)
     bands = _flatten_bands(pyr, kind)
 
     dt = np.dtype(bands[0].dtype)
@@ -708,16 +693,13 @@ def _decode_common(data: bytes, partial: bool):
     return h, _assemble(h, bands), tuple(status)
 
 
-def _timed_decode(data: bytes, partial: bool):
-    """Instrumented wrapper around :func:`_decode_common`: span +
-    duration/byte metrics (``codec.decode_*``) per container decode."""
-    t0 = time.perf_counter()
+def _traced_decode(data: bytes, partial: bool):
+    """Instrumented wrapper around :func:`_decode_common`: one span per
+    container decode and its bytes into ``codec.decode_bytes``."""
     name = "codec.decode_pyramid_partial" if partial else "codec.decode_pyramid"
     with obs.span(name, subsystem="codec"):
         out = _decode_common(data, partial=partial)
-    obs.counter("codec.decode_calls").inc()
     obs.counter("codec.decode_bytes").inc(len(data))
-    obs.histogram("codec.decode_ms").observe((time.perf_counter() - t0) * 1e3)
     return out
 
 
@@ -729,7 +711,7 @@ def decode_pyramid(data: bytes) -> DecodedPyramid:
     that cannot heal raises :class:`CorruptBandError`; use
     :func:`decode_pyramid_partial` to recover the intact bands instead.
     """
-    h, pyr, status = _timed_decode(data, partial=False)
+    h, pyr, status = _traced_decode(data, partial=False)
     return DecodedPyramid(
         pyramid=pyr,
         kind=h.kind,
@@ -752,7 +734,7 @@ def decode_pyramid_partial(data: bytes) -> PartialDecode:
     and every other band is bit-exact.  v1 blobs carry no per-band
     CRCs, so for them this is equivalent to :func:`decode_pyramid`.
     """
-    h, pyr, status = _timed_decode(data, partial=True)
+    h, pyr, status = _traced_decode(data, partial=True)
     return PartialDecode(
         pyramid=pyr,
         kind=h.kind,

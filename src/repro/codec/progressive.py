@@ -41,6 +41,7 @@ from typing import Any, List, NamedTuple, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.codec import container as C
 from repro.codec.errors import (
     CodecError,
@@ -348,4 +349,5 @@ def reconstruct(dec: C.DecodedPyramid, backend: Optional[str] = None):
     """
     if dec.levels == 0:
         return dec.pyramid.approx if hasattr(dec.pyramid, "approx") else dec.pyramid.ll
-    return C.inverse_transform(dec, backend=backend)
+    with obs.span("codec.inverse", subsystem="codec"):  # dispatch, no sync
+        return C.inverse_transform(dec, backend=backend)

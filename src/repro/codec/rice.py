@@ -31,7 +31,10 @@ Host-facing entry points (``encode_band`` / ``decode_band``) take and
 return numpy arrays and chunk internally (``CHUNK_BLOCKS`` blocks per
 compiled dispatch, padded to power-of-two buckets) so gigabyte bands
 never materialize the whole scatter workspace and the jit cache stays
-bounded.
+bounded.  Each records ONE span per band (``codec.encode_band`` /
+``codec.decode_band``) around its chunk loop, with the loop's
+``chunks``, ``blocks`` and ``wait_s`` (seconds blocked on the device)
+as attributes — never a span per chunk.
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import backend as B
 
 # block geometry: 256 samples per Rice block keeps the k-table overhead
@@ -289,6 +293,18 @@ def n_blocks(count: int) -> int:
     return -(-count // BLOCK_VALUES)
 
 
+def _to_host(*arrays: jax.Array) -> list:
+    """``arrays`` as numpy; the time blocked on the device goes to the open
+    span's ``wait_s``.  The copies are enqueued before the block, so they
+    follow the computation on the device as a bare ``np.asarray`` would,
+    and the host waits out only what is left of them."""
+    for a in arrays:
+        a.copy_to_host_async()
+    with obs.waiting():
+        jax.block_until_ready(arrays)
+    return [np.asarray(a) for a in arrays]
+
+
 def encode_band(
     x: np.ndarray, backend: Optional[str] = None
 ) -> Tuple[bytes, np.ndarray, np.ndarray]:
@@ -313,23 +329,25 @@ def encode_band(
     ks = np.zeros(nb, np.uint8)
     blens = np.zeros(nb, np.int64)
     parts = []
-    for start in range(0, nb, CHUNK_BLOCKS):
-        chunk = blocks[start : start + CHUNK_BLOCKS]
-        rows = chunk.shape[0]
-        bucket = _bucket(rows, CHUNK_BLOCKS)
-        if bucket != rows:
-            chunk = np.concatenate(
-                [chunk, np.zeros((bucket - rows, BLOCK_VALUES), np.int32)]
+    with obs.span("codec.encode_band", subsystem="codec",
+                  chunks=-(-nb // CHUNK_BLOCKS), blocks=nb, wait_s=0.0):
+        for start in range(0, nb, CHUNK_BLOCKS):
+            chunk = blocks[start : start + CHUNK_BLOCKS]
+            rows = chunk.shape[0]
+            bucket = _bucket(rows, CHUNK_BLOCKS)
+            if bucket != rows:
+                chunk = np.concatenate(
+                    [chunk, np.zeros((bucket - rows, BLOCK_VALUES), np.int32)]
+                )
+            by, nbits, k = _to_host(
+                *_encode_chunk(jnp.asarray(chunk), pack_backend=resolved)
             )
-        by, nbits, k = _encode_chunk(
-            jnp.asarray(chunk), pack_backend=resolved
-        )
-        by = np.asarray(by)[:rows]
-        blen = (np.asarray(nbits)[:rows] + 7) // 8
-        ks[start : start + rows] = np.asarray(k)[:rows].astype(np.uint8)
-        blens[start : start + rows] = blen
-        mask = np.arange(BYTES_CAP)[None, :] < blen[:, None]
-        parts.append(by[mask].tobytes())
+            by = by[:rows]
+            blen = (nbits[:rows] + 7) // 8
+            ks[start : start + rows] = k[:rows].astype(np.uint8)
+            blens[start : start + rows] = blen
+            mask = np.arange(BYTES_CAP)[None, :] < blen[:, None]
+            parts.append(by[mask].tobytes())
     return b"".join(parts), ks, blens.astype(np.uint16)
 
 
@@ -357,18 +375,20 @@ def decode_band(
     raw = np.frombuffer(payload, np.uint8)
     offs = np.concatenate([[0], np.cumsum(blens)])
     out = np.zeros(nb * BLOCK_VALUES, np.int32)
-    for start in range(0, nb, CHUNK_BLOCKS):
-        rows = min(CHUNK_BLOCKS, nb - start)
-        lens_c = blens[start : start + rows]
-        maxlen = _bucket(max(int(lens_c.max()), 8))
-        bucket = _bucket(rows, CHUNK_BLOCKS)
-        mat = np.zeros((bucket, maxlen), np.uint8)
-        mask = np.arange(maxlen)[None, :] < lens_c[:, None]
-        mat[:rows][mask] = raw[offs[start] : offs[start + rows]]
-        kc = np.zeros(bucket, np.int32)
-        kc[:rows] = ks[start : start + rows]
-        dec = np.asarray(_decode_chunk(jnp.asarray(mat), jnp.asarray(kc)))
-        out[
-            start * BLOCK_VALUES : start * BLOCK_VALUES + rows * BLOCK_VALUES
-        ] = dec[:rows].reshape(-1)
+    with obs.span("codec.decode_band", subsystem="codec",
+                  chunks=-(-nb // CHUNK_BLOCKS), blocks=nb, wait_s=0.0):
+        for start in range(0, nb, CHUNK_BLOCKS):
+            rows = min(CHUNK_BLOCKS, nb - start)
+            lens_c = blens[start : start + rows]
+            maxlen = _bucket(max(int(lens_c.max()), 8))
+            bucket = _bucket(rows, CHUNK_BLOCKS)
+            mat = np.zeros((bucket, maxlen), np.uint8)
+            mask = np.arange(maxlen)[None, :] < lens_c[:, None]
+            mat[:rows][mask] = raw[offs[start] : offs[start + rows]]
+            kc = np.zeros(bucket, np.int32)
+            kc[:rows] = ks[start : start + rows]
+            (dec,) = _to_host(_decode_chunk(jnp.asarray(mat), jnp.asarray(kc)))
+            out[
+                start * BLOCK_VALUES : start * BLOCK_VALUES + rows * BLOCK_VALUES
+            ] = dec[:rows].reshape(-1)
     return out[:count]

@@ -10,7 +10,9 @@ The measurement substrate under every other subsystem (DESIGN.md §15):
                 ring buffer; warning sites ALSO emit here, so the Nth
                 degrade is queryable even though the warning fired once
     trace.py    span-based tracing — host-side wall time per region,
-                optional ``jax.profiler.TraceAnnotation`` device hook,
+                span and parent ids, every span also a
+                ``jax.profiler.TraceAnnotation`` (the profiler's clock),
+                device waits charged to the innermost open span,
                 Chrome-trace JSON export (loads in Perfetto)
 
 One process-wide instance of each lives here; instrumentation sites use
@@ -21,14 +23,16 @@ the module-level helpers::
     obs.histogram("serve.batch_latency_ms").observe(ms)
     obs.emit(obs.DegradeEvent(subsystem="kernels", requested="pallas",
                               resolved="xla", reason="..."))
-    with obs.span("serve.step", subsystem="serve", bucket="256x256"):
+    with obs.span("serve.step", subsystem="serve", bucket="256x256") as attrs:
         ...
+        with obs.waiting():  # blocked seconds -> attrs["wait_s"]
+            jax.block_until_ready(out)
 
 Everything is host-side and allocation-light: no sync points, nothing
 inside jitted code, one flag read on the disabled path
 (``REPRO_OBS=0`` / :func:`set_enabled`).  The serve throughput bench
-A/Bs instrumented-vs-bare and ``benchmarks/gate.py check_obs`` bounds
-the ratio, so "cheap enough to leave on" is a gated claim, not a hope.
+A/Bs instrumented-vs-bare on the CPU and ``benchmarks/gate.py
+check_obs`` bounds the ratio there.
 
 Metric names are ``subsystem.metric`` (subsystems: ``kernels``,
 ``codec``, ``serve``, ``ckpt``, ``collectives``); :func:`subsystems`
@@ -71,6 +75,7 @@ gauge = registry.gauge
 histogram = registry.histogram
 emit = events.emit
 span = tracer.span
+waiting = tracer.waiting
 
 set_enabled = _state.set_enabled
 is_enabled = _state.is_enabled
@@ -175,6 +180,7 @@ __all__ = [
     "span",
     "subsystems",
     "tracer",
+    "waiting",
     "warn_event",
     "write_chrome_trace",
 ]

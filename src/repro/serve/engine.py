@@ -381,18 +381,26 @@ class WaveletServeEngine:
         bucket, active = self.scheduler.next_batch(self.batch_slots)
         if bucket is None:
             return overdue
+        now = time.monotonic()
         bucket_label = "x".join(str(s) for s in bucket)
-        t0 = time.perf_counter()
-        # static batch shape: the executable is compiled for
-        # (batch_slots,) + bucket, so unfilled slots — and the padding
-        # margin of undersized requests — are ZERO-filled (zeros ride the
-        # transform and are discarded; they never repeat live data)
-        batch = np.zeros((self.batch_slots,) + bucket, np.int32)
-        for i, r in enumerate(active):
-            batch[(i,) + tuple(slice(0, s) for s in r.image.shape)] = r.image
-        key = self._exec_key(bucket)
-        with obs.span("serve.step", subsystem="serve", bucket=bucket_label,
-                      n=len(active)):
+        # the root span of a served batch: its subtree splits the step into
+        # batch assembly, transform dispatch, per-request slicing and the
+        # response encode (whose device waits carry ``wait_s``)
+        with obs.span(
+            "serve.step", subsystem="serve", bucket=bucket_label, n=len(active),
+            uids=[r.uid for r in active],
+            queue_wait_ms=[(now - (r.submitted_at or now)) * 1e3 for r in active],
+        ):
+            t0 = time.perf_counter()
+            # static batch shape: the executable is compiled for
+            # (batch_slots,) + bucket, so unfilled slots — and the padding
+            # margin of undersized requests — are ZERO-filled (zeros ride
+            # the transform and are discarded; they never repeat live data)
+            with obs.span("serve.assemble", subsystem="serve"):
+                batch = np.zeros((self.batch_slots,) + bucket, np.int32)
+                for i, r in enumerate(active):
+                    batch[(i,) + tuple(slice(0, s) for s in r.image.shape)] = r.image
+            key = self._exec_key(bucket)
             try:
                 pyr = self._transform_with_retry(batch, key)
             except Exception:
@@ -406,17 +414,18 @@ class WaveletServeEngine:
                 self._expired_out.extend(expired)
                 self.scheduler.requeue_front(bucket, live)
                 raise
-            for i, r in enumerate(active):
-                r.pyramid = jax.tree_util.tree_map(lambda b, i=i: b[i], pyr)
+            with obs.span("serve.slice", subsystem="serve"):
+                for i, r in enumerate(active):
+                    r.pyramid = jax.tree_util.tree_map(lambda b, i=i: b[i], pyr)
             if self.encode_response and active:
                 self._encode_batch(active, pyr)
-        for r in active:
-            r.done = True
-        obs.histogram("serve.batch_latency_ms", bucket=bucket_label).observe(
-            (time.perf_counter() - t0) * 1e3
-        )
-        obs.counter("serve.requests_served").inc(len(active))
-        obs.counter("serve.batches").inc()
+            for r in active:
+                r.done = True
+            obs.histogram("serve.batch_latency_ms", bucket=bucket_label).observe(
+                (time.perf_counter() - t0) * 1e3
+            )
+            obs.counter("serve.requests_served").inc(len(active))
+            obs.counter("serve.batches").inc()
         return overdue + active
 
     def run(self, requests: List[TransformRequest]) -> List[TransformRequest]:

@@ -145,14 +145,15 @@ class TransformExecutor:
         else:
             self.hits += 1
             obs.counter("serve.executor_cache", outcome="hit").inc()
-        obs.gauge("serve.executor_hit_rate").set(self.hit_rate())
         return fn
 
     def run(self, fn: Callable, batch, key: ExecKey):
         """Run ``batch`` through an executable :meth:`executable` returned.
 
-        The span measures HOST dispatch wall time (async dispatch — no
-        added sync).
+        The ``serve.transform`` span is the transform's dispatch time on
+        the host: the call returns before the device finishes, and no
+        sync is added, so the device's part shows up wherever the caller
+        first blocks on the result.
         """
         bucket = "x".join(str(s) for s in key.bucket)
         with obs.span("serve.transform", subsystem="serve", bucket=bucket):

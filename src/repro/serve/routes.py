@@ -27,14 +27,21 @@ reconstructs at tier ``L`` to the BUCKET's level-``(levels-L)`` shape;
 the route crops to the request's own ceil-halved shape
 (``ceil(orig / 2**(levels-L))`` per axis — the lifting split sizes), so
 thumbnails of padded requests carry no padding margin.
+
+Each tier call is one root span, ``serve.read`` (attributes ``uid`` and
+``tier``, the level count the answer holds), over the per-band
+``codec.decode_band`` spans and the ``codec.inverse`` dispatch; the
+block on the inverse's result adds to its ``wait_s``.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
+import jax
 import numpy as np
 
+from repro import obs
 from repro.codec import progressive
 from repro.serve.engine import TransformRequest
 
@@ -115,20 +122,32 @@ class ProgressiveServeRoute:
             raise KeyError(f"no stored response for request {uid}") from None
 
     def _row(self, arr, entry: StoredResponse) -> np.ndarray:
+        with obs.waiting():
+            jax.block_until_ready(arr)
         out = np.asarray(arr)
         if entry.batch_index is not None:
             out = out[entry.batch_index]
         return out
 
+    def _refine(self, entry: StoredResponse, levels: int, up_to_level: int,
+                heal: bool, partial: bool) -> np.ndarray:
+        dec = progressive.decode_progressive(
+            entry.source, up_to_level, heal=heal, partial=partial
+        )
+        arr = self._row(progressive.reconstruct(dec, backend=self.backend), entry)
+        crop = tier_shape(entry.image_shape, levels, up_to_level)
+        return arr[tuple(slice(0, s) for s in crop)]
+
     # -- tiers ---------------------------------------------------------------
 
     def thumbnail(self, uid: int, *, heal: bool = True) -> np.ndarray:
         """The approximation band for ``uid`` — header + ONE band read."""
-        entry = self._entry(uid)
-        dec = progressive.decode_lowband(entry.source, heal=heal)
-        thumb = self._row(dec.band, entry)
-        crop = tier_shape(entry.image_shape, dec.levels, 0)
-        return thumb[tuple(slice(0, s) for s in crop)]
+        with obs.span("serve.read", subsystem="serve", uid=uid, tier=0):
+            entry = self._entry(uid)
+            dec = progressive.decode_lowband(entry.source, heal=heal)
+            thumb = self._row(dec.band, entry)
+            crop = tier_shape(entry.image_shape, dec.levels, 0)
+            return thumb[tuple(slice(0, s) for s in crop)]
 
     def refine(
         self,
@@ -139,20 +158,18 @@ class ProgressiveServeRoute:
         partial: bool = False,
     ) -> np.ndarray:
         """``uid`` reconstructed from its coarsest ``up_to_level`` levels."""
-        entry = self._entry(uid)
-        h = progressive.read_header(entry.source)
-        dec = progressive.decode_progressive(
-            entry.source, up_to_level, heal=heal, partial=partial
-        )
-        arr = self._row(progressive.reconstruct(dec, backend=self.backend), entry)
-        crop = tier_shape(entry.image_shape, h.levels, up_to_level)
-        return arr[tuple(slice(0, s) for s in crop)]
+        with obs.span("serve.read", subsystem="serve", uid=uid, tier=up_to_level):
+            entry = self._entry(uid)
+            h = progressive.read_header(entry.source)
+            return self._refine(entry, h.levels, up_to_level, heal, partial)
 
     def full(self, uid: int, *, heal: bool = True) -> np.ndarray:
         """The original samples, bit-exact (every byte range read)."""
-        entry = self._entry(uid)
-        h = progressive.read_header(entry.source)
-        return self.refine(uid, h.levels, heal=heal)
+        with obs.span("serve.read", subsystem="serve", uid=uid) as attrs:
+            entry = self._entry(uid)
+            h = progressive.read_header(entry.source)
+            attrs["tier"] = h.levels
+            return self._refine(entry, h.levels, h.levels, heal, partial=False)
 
     def tiers(self, uid: int) -> Dict[int, Shape]:
         """Available fidelity tiers: ``{up_to_level: shape}`` for ``uid``."""

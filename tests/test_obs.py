@@ -343,6 +343,85 @@ def test_chrome_trace_is_valid_and_loadable_shape(tmp_path):
     assert a["ts"] <= b["ts"] and b["ts"] + b["dur"] <= a["ts"] + a["dur"] + 1
 
 
+def test_span_and_parent_ids_nest_three_deep_and_stay_apart_per_thread():
+    barrier = threading.Barrier(2)
+
+    def nest(tag):
+        with obs.span(f"{tag}.a"):
+            barrier.wait(timeout=10)  # both threads hold a span open at once
+            with obs.span(f"{tag}.b"):
+                with obs.span(f"{tag}.c"):
+                    barrier.wait(timeout=10)
+
+    threads = [threading.Thread(target=nest, args=(t,)) for t in ("x", "y")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    by = {s.name: s for s in obs.tracer.spans()}
+    assert len(by) == 6 and len({s.span_id for s in by.values()}) == 6
+    for tag in ("x", "y"):
+        a, b, c = by[f"{tag}.a"], by[f"{tag}.b"], by[f"{tag}.c"]
+        assert a.parent_id is None
+        assert b.parent_id == a.span_id and c.parent_id == b.span_id
+        assert a.tid == b.tid == c.tid
+    assert by["x.a"].tid != by["y.a"].tid
+    events = {e["name"]: e["args"] for e in obs.export_chrome_trace()["traceEvents"]}
+    assert events["x.c"]["parent_id"] == events["x.b"]["span_id"]
+
+
+def test_wait_lands_on_the_innermost_open_span():
+    with obs.span("t.outer") as outer:
+        obs.tracer.add_wait(0.5)
+        with obs.span("t.inner", chunks=3) as inner:
+            obs.tracer.add_wait(0.25)
+            with obs.waiting():
+                pass
+            inner["blocks"] = 7
+        obs.tracer.add_wait(0.5)
+    obs.tracer.add_wait(9.0)  # no span open: dropped
+    (o,) = obs.tracer.spans(name="t.outer")
+    (i,) = obs.tracer.spans(name="t.inner")
+    assert outer is o.args and o.args == {"wait_s": 1.0}
+    assert 0.25 <= i.args["wait_s"] < 0.3 and inner is i.args
+    assert i.args["chunks"] == 3 and i.args["blocks"] == 7
+
+
+def test_disabled_tracer_records_nothing_and_yields_usable_attrs():
+    with obs.disabled():
+        with obs.span("t.off", subsystem="t", n=2) as attrs:
+            attrs["chunks"] = 4
+            obs.tracer.add_wait(1.0)
+            with obs.waiting():
+                pass
+    assert attrs == {"n": 2, "chunks": 4}
+    assert obs.tracer.total == 0 and len(obs.tracer) == 0
+
+
+def test_a_span_imports_nothing_once_the_first_has_run():
+    import builtins
+
+    with obs.span("t.first"):
+        pass
+    calls = []
+    real = builtins.__import__
+
+    def counting(*a, **k):
+        calls.append(a[0])
+        return real(*a, **k)
+
+    builtins.__import__ = counting
+    try:
+        for _ in range(100):
+            with obs.span("t.again", subsystem="t"):
+                pass
+    finally:
+        builtins.__import__ = real
+    assert calls == []
+    assert len(obs.tracer.spans(name="t.again")) == 100
+
+
 def test_span_exceptions_still_record():
     with pytest.raises(RuntimeError):
         with obs.span("t.fail", subsystem="serve"):
