@@ -6,8 +6,8 @@ the resulting pyramids into compact, self-describing bytes and back,
 bit-exactly.
 
     rice.py       adaptive Golomb-Rice coder — zigzag mapping, per-block
-                  shift-add optimal ``k`` selection on device, vectorized
-                  prefix-sum/scatter bit-packing with a Pallas pack
+                  shift-add optimal ``k`` selection on device, per-value
+                  shift-or packing into 32-bit words with a Pallas pack
                   kernel under the ``kernels/backend.py`` dispatch policy
     container.py  one pyramid -> one self-describing blob (magic/version,
                   kind/scheme/mode/levels/shape/dtype, per-band k tables

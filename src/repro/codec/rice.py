@@ -15,12 +15,15 @@ independent blocks of ``BLOCK_VALUES`` samples:
     escape to ``Q_MAX`` ones followed by the raw 32-bit value, which
     bounds every code at ``LMAX`` bits (outlier-proof, including the
     zigzag of INT32_MIN);
-  * bit-packing is fully vectorized: per-value code lengths prefix-sum
-    into bit offsets, a scatter places every code bit, and the bit->word
-    pack runs through :func:`pack_words` — a Pallas kernel where the
-    resolved backend compiles one (TPU, or explicit request) and the
-    same shift-or math under ``jax.jit`` on the XLA fallback, selected
-    by the ``kernels/backend.py`` policy.  All paths are bit-identical.
+  * packing works per value, never per bit: a code is at most two
+    pieces of at most 32 bits (an escape's ``Q_MAX`` ones, then the low
+    32 bits of the code), and each piece lands in the word its bit
+    offset names and, where it crosses a boundary, the next one.
+    :func:`pack_words` runs on the path the ``kernels/backend.py``
+    policy resolves: a Pallas kernel (TPU, or explicit request) that
+    shift-ors the pieces into words with blocks on lanes, or on the XLA
+    fallback a segment sum of the pieces over their word indices.  All
+    paths are bit-identical.
 
 Blocks are byte-aligned and self-contained (own ``k``, own byte length),
 so decode parallelizes ACROSS blocks: one ``lax.scan`` of
@@ -30,7 +33,7 @@ step's unary run in O(1) via a precomputed next-zero suffix scan.
 Host-facing entry points (``encode_band`` / ``decode_band``) take and
 return numpy arrays and chunk internally (``CHUNK_BLOCKS`` blocks per
 compiled dispatch, padded to power-of-two buckets) so gigabyte bands
-never materialize the whole scatter workspace and the jit cache stays
+never materialize on the device at once and the jit cache stays
 bounded.  Each records ONE span per band (``codec.encode_band`` /
 ``codec.decode_band``) around its chunk loop, with the loop's
 ``chunks``, ``blocks`` and ``wait_s`` (seconds blocked on the device)
@@ -55,12 +58,12 @@ Q_MAX = 8  # unary quotient cap; q >= Q_MAX escapes to 32 raw bits
 K_MAX = 24  # largest Rice parameter the cost scan considers
 LMAX = Q_MAX + 32  # longest code: escape (non-escape max is Q_MAX+K_MAX)
 
-_STRIDE_BITS = BLOCK_VALUES * LMAX  # per-block bit workspace (10240)
-_WORDS = _STRIDE_BITS // 32
-BYTES_CAP = _STRIDE_BITS // 8  # worst-case encoded bytes per block
+BYTES_CAP = BLOCK_VALUES * LMAX // 8  # worst-case encoded bytes per block
+_WORDS = BYTES_CAP // 4  # packed 32-bit words per block (320)
+_LANES = 128  # blocks per pack-kernel tile: one vreg row of lanes
 
-# encode/decode dispatch width: blocks per compiled chunk (bounds the
-# scatter workspace at ~128*256*40*4B ≈ 5 MB per temporary)
+# encode/decode dispatch width: blocks per compiled chunk (one pack-kernel
+# lane tile; bounds the decoder's per-bit workspace)
 CHUNK_BLOCKS = 128
 
 
@@ -91,50 +94,147 @@ def unzigzag(u: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Bit -> word packing: the backend-dispatched kernel stage.
+# Code -> word packing: the backend-dispatched kernel stage.
 # ---------------------------------------------------------------------------
 
 
-def _pack_kernel(bits_ref, words_ref):
-    """OR 32 single-bit planes into packed words (bit 0 at the MSB)."""
-    acc = jnp.left_shift(bits_ref[:, 0, :], 31)
-    for i in range(1, 32):
-        acc = jnp.bitwise_or(acc, jnp.left_shift(bits_ref[:, i, :], 31 - i))
-    words_ref[...] = acc
+def _split(v: jax.Array, end: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A right-aligned piece ``v`` whose last bit falls at bit ``end`` of
+    a two-word window (bit 0 is the first word's MSB; ``end`` in 0..63,
+    the piece no longer than ``end`` nor than 32 bits) -> its part in each
+    word.  Every shift stays in 0..31: a piece of length 0 is 0."""
+    over = end - 32
+    head = jnp.where(
+        over > 0,
+        jax.lax.shift_right_logical(v, jnp.clip(over, 0, 31)),
+        jnp.left_shift(v, jnp.clip(-over, 0, 31)),
+    )
+    tail = jnp.where(over > 0, jnp.left_shift(v, jnp.clip(32 - over, 0, 31)), 0)
+    return head, tail
 
 
-def _pack_words_pallas(bits3: jax.Array, interpret: bool) -> jax.Array:
+def _pieces(lo: jax.Array, lens: jax.Array):
+    """A code of ``lens`` bits whose low 32 are ``lo`` -> two pieces of at
+    most 32 bits: the ``lens - 32`` leading ones of an escape (length 0
+    for any other code), then ``lo``."""
+    hl = jnp.maximum(lens - 32, 0)
+    return (jnp.left_shift(1, hl) - 1, hl), (lo, lens - hl)
+
+
+def _put(state, v, ln):
+    """Append one piece to each lane's partial word.  Returns the new
+    state and the word the piece completed, with its row (-1: none)."""
+    acc, fill, widx = state
+    end = fill + ln
+    head, tail = _split(v, end)
+    word = jnp.bitwise_or(acc, head)
+    full = end >= 32
+    row = jnp.where(full, widx, -1)
+    state = (
+        jnp.where(full, tail, word),
+        jnp.where(full, end - 32, end),
+        widx + full.astype(jnp.int32),
+    )
+    return state, row, word
+
+
+def _pack_kernel(lo_ref, lens_ref, words_ref):
+    """Shift-or pack of one lane tile: blocks on lanes, values and words
+    on rows.  Each lane carries its partial word, fill and word index
+    through its values in order.  The words that a tile of eight values
+    completes land in their rows by a select over the row groups, since
+    every lane writes rows of its own."""
+    groups, sub, lanes = words_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 0)
+    words_ref[...] = jnp.zeros(words_ref.shape, jnp.int32)
+
+    def write(done):
+        def group(j, _):
+            blk = words_ref[j]
+            for row, word in done:
+                blk = jnp.where(rows == row - sub * j, word, blk)
+            words_ref[j] = blk
+
+        jax.lax.fori_loop(0, groups, group, None)
+
+    def step(g, state):
+        lo, lens = lo_ref[g], lens_ref[g]
+        done = []
+        for r in range(sub):
+            for v, ln in _pieces(lo[r : r + 1], lens[r : r + 1]):
+                state, row, word = _put(state, v, ln)
+                done.append((row, word))
+        write(done)
+        return state
+
+    zero = jnp.zeros((1, lanes), jnp.int32)
+    acc, fill, widx = jax.lax.fori_loop(
+        0, lo_ref.shape[0], step, (zero, zero, zero)
+    )
+    write([(jnp.where(fill > 0, widx, -1), acc)])  # the last partial word
+
+
+def _pack_words_pallas(
+    lo: jax.Array, lens: jax.Array, interpret: bool
+) -> jax.Array:
     from jax.experimental import pallas as pl
 
-    nb, _, nwords = bits3.shape
-    rows = min(8, nb)
-    return pl.pallas_call(
+    nb = lo.shape[0]
+    lanes = -(-nb // _LANES) * _LANES  # padded lanes carry 0-bit codes
+
+    def lay(a):  # (nb, BLOCK_VALUES) -> (BLOCK_VALUES // 8, 8, lanes)
+        a = jnp.pad(a.T, ((0, 0), (0, lanes - nb)))
+        return a.reshape(BLOCK_VALUES // 8, 8, lanes)
+
+    spec = pl.BlockSpec((BLOCK_VALUES // 8, 8, _LANES), lambda c: (0, 0, c))
+    words = pl.pallas_call(
         _pack_kernel,
-        grid=(nb // rows,),
-        in_specs=[pl.BlockSpec((rows, 32, nwords), lambda r: (r, 0, 0))],
-        out_specs=pl.BlockSpec((rows, nwords), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, nwords), jnp.int32),
+        grid=(lanes // _LANES,),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((_WORDS // 8, 8, _LANES), lambda c: (0, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((_WORDS // 8, 8, lanes), jnp.int32),
         interpret=interpret,
-    )(bits3)
+    )(lay(lo), lay(lens))
+    return words.reshape(_WORDS, lanes)[:, :nb].T
 
 
-def _pack_words_xla(bits3: jax.Array) -> jax.Array:
-    sh = (31 - jnp.arange(32, dtype=jnp.int32)).reshape(1, 32, 1)
-    # codes occupy disjoint bits, so the sum of shifted planes IS the or
-    return jnp.sum(jnp.left_shift(bits3, sh), axis=1, dtype=jnp.int32)
+def _pack_words_xla(
+    lo: jax.Array, lens: jax.Array, offs: jax.Array
+) -> jax.Array:
+    nb = lo.shape[0]
+    base = jnp.arange(nb, dtype=jnp.int32)[:, None] * _WORDS
+    idx, val = [], []
+    for v, ln in _pieces(lo, lens):
+        head, tail = _split(v, (offs & 31) + ln)
+        w = base + jnp.right_shift(offs, 5)
+        idx += [w, w + 1]
+        val += [head, tail]
+        offs = offs + ln
+    # codes occupy disjoint bits, so the sum of the pieces IS their or;
+    # a tail past the chunk's last word is 0 and falls off the end
+    words = jax.ops.segment_sum(
+        jnp.concatenate([a.reshape(-1) for a in val]),
+        jnp.concatenate([a.reshape(-1) for a in idx]),
+        num_segments=nb * _WORDS,
+    )
+    return words.reshape(nb, _WORDS)
 
 
-def pack_words(bits3: jax.Array, pack_backend: str) -> jax.Array:
-    """(nb, 32, nwords) 0/1 planes -> (nb, nwords) packed int32 words.
+def pack_words(
+    lo: jax.Array, lens: jax.Array, offs: jax.Array, pack_backend: str
+) -> jax.Array:
+    """Per-value codes -> (nb, _WORDS) packed int32 words.
 
-    Word layout matches the byte stream: bit ``32w + i`` of a block is
-    bit ``31 - i`` of word ``w`` (MSB-first within every byte).
-    ``pack_backend`` is a RESOLVED backend name (``kernels/backend.py``);
-    all three paths produce bit-identical words.
+    ``lo`` holds the low 32 bits of each code (int32 bit pattern),
+    ``lens`` its length in bits and ``offs`` their exclusive prefix sum
+    along the block.  Bit ``32w + i`` of a block is bit ``31 - i`` of
+    word ``w`` (MSB-first within every byte).  ``pack_backend`` is a
+    RESOLVED backend name (``kernels/backend.py``); all three paths
+    produce bit-identical words.
     """
     if pack_backend == "xla":
-        return _pack_words_xla(bits3)
-    return _pack_words_pallas(bits3, interpret=(pack_backend == "interpret"))
+        return _pack_words_xla(lo, lens, offs)
+    return _pack_words_pallas(lo, lens, interpret=(pack_backend == "interpret"))
 
 
 # ---------------------------------------------------------------------------
@@ -175,44 +275,12 @@ def _encode_chunk(
     nbits = offs[:, -1] + lens[:, -1]
     rem = u & (jnp.left_shift(jnp.uint32(1), k_u) - jnp.uint32(1))
 
-    # materialize every code bit on a (nb, BLOCK, LMAX) grid
-    jj = jnp.arange(LMAX, dtype=jnp.int32)
-    q3, e3 = q_c[..., None], esc[..., None]
-    m = jj - q3 - 1  # remainder bit index (valid where 0 <= m < k)
-    k3 = ks[:, None, None]
-    rbit = (
-        jnp.right_shift(
-            rem[..., None], jnp.clip(k3 - 1 - m, 0, 31).astype(jnp.uint32)
-        )
-        & jnp.uint32(1)
-    ).astype(jnp.int32)
-    t = jj - Q_MAX  # escape raw-bit index (valid where 0 <= t < 32)
-    ebit = (
-        jnp.right_shift(
-            u[..., None], jnp.clip(31 - t, 0, 31).astype(jnp.uint32)
-        )
-        & jnp.uint32(1)
-    ).astype(jnp.int32)
-    bits = jnp.where(
-        jj < q3,
-        1,  # unary ones (both normal and escape prefixes)
-        jnp.where(
-            e3,
-            jnp.where((t >= 0) & (t < 32), ebit, 0),
-            jnp.where((m >= 0) & (m < k3), rbit, 0),  # jj == q3 -> terminator 0
-        ),
-    )
-    valid = jj < lens[..., None]
-
-    # scatter each code's bits to its prefix-sum offset (invalid -> drop)
-    pos = offs[..., None] + jj
-    gpos = jnp.arange(nb, dtype=jnp.int32)[:, None, None] * _STRIDE_BITS + pos
-    gpos = jnp.where(valid, gpos, nb * _STRIDE_BITS)
-    buf = jnp.zeros((nb * _STRIDE_BITS,), jnp.int32)
-    buf = buf.at[gpos.reshape(-1)].set(bits.reshape(-1), mode="drop")
-
-    bits3 = jnp.swapaxes(buf.reshape(nb, _WORDS, 32), -1, -2)
-    words = pack_words(bits3, pack_backend)
+    # the low 32 bits of each code: q ones, the 0, the k remainder bits;
+    # an escape's are the raw u after its Q_MAX ones
+    ones = jnp.left_shift(jnp.uint32(1), q_c.astype(jnp.uint32)) - jnp.uint32(1)
+    code = jnp.left_shift(ones, k_u + jnp.uint32(1)) | rem
+    lo = jax.lax.bitcast_convert_type(jnp.where(esc, u, code), jnp.int32)
+    words = pack_words(lo, lens, offs, pack_backend)
     by = jnp.stack(
         [(jnp.right_shift(words, s) & 0xFF) for s in (24, 16, 8, 0)], axis=-1
     )
@@ -313,7 +381,7 @@ def encode_band(
     Returns ``(payload, k_table, byte_lengths)`` — the byte-aligned
     concatenated block bitstreams plus the per-block Rice parameters
     (uint8) and encoded byte counts (uint16) the container serializes.
-    ``backend`` selects the bit-pack kernel path (None = policy default).
+    ``backend`` selects the pack kernel path (None = policy default).
     """
     flat = np.ascontiguousarray(x).reshape(-1).astype(np.int32)
     count = flat.size

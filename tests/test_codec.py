@@ -8,6 +8,7 @@ and the degenerate shapes of test_degenerate.py.  Consumer wiring (ckpt
 ``wz-rice``, measured ``encoded_bytes_*``, ``pod_encoded_bytes``, the
 serve encoded-response route, the stream layer) is covered here as well.
 """
+import hashlib
 import io
 import json
 import zlib
@@ -109,6 +110,118 @@ def test_property_rice_roundtrip(n, lo, hi, seed):
     payload, ks, lens = rice.encode_band(x)
     np.testing.assert_array_equal(
         rice.decode_band(payload, ks, lens, n), x
+    )
+
+
+# ---------------------------------------------------------------------------
+# Rice bytes against a sequential bit writer built from the format alone.
+# ---------------------------------------------------------------------------
+
+
+def _sequential_rice(x):
+    """The coded stream of ``x``, one bit at a time, from the documented
+    format: blocks of ``BLOCK_VALUES`` (the last zero-padded), zigzag,
+    the ``k`` of least total length (the smallest on a tie), then per
+    value ``q = u >> k`` ones, a 0 and the ``k`` low bits of ``u``, or
+    ``Q_MAX`` ones and all 32 bits of ``u`` where ``q >= Q_MAX``; each
+    block MSB-first, padded to whole bytes.  Returns ``(payload, k_table,
+    byte_lengths, code_offsets)``."""
+    flat = np.asarray(x, np.int64).reshape(-1)
+    nb = -(-flat.size // rice.BLOCK_VALUES)
+    blocks = np.zeros(nb * rice.BLOCK_VALUES, np.int64)
+    blocks[: flat.size] = flat
+    u_all = ((blocks << 1) ^ (blocks >> 63)) & 0xFFFFFFFF
+    payload, ks, blens, offsets = [], [], [], []
+    for u in u_all.reshape(nb, rice.BLOCK_VALUES):
+        cost = [
+            np.where((u >> k) >= rice.Q_MAX, rice.Q_MAX + 32, (u >> k) + 1 + k).sum()
+            for k in range(rice.K_MAX + 1)
+        ]
+        k = int(np.argmin(cost))
+        bits = []
+        for v in u.tolist():
+            offsets.append(sum(map(len, bits)))
+            q = v >> k
+            if q >= rice.Q_MAX:
+                bits.append("1" * rice.Q_MAX + format(v, "032b"))
+            else:
+                bits.append("1" * q + "0" + (format(v & ((1 << k) - 1), f"0{k}b") if k else ""))
+        s = "".join(bits)
+        s += "0" * (-len(s) % 8)
+        payload.append(int(s, 2).to_bytes(len(s) // 8, "big") if s else b"")
+        ks.append(k)
+        blens.append(len(s) // 8)
+    return b"".join(payload), np.array(ks), np.array(blens), np.array(offsets)
+
+
+def _golden_cases():
+    rng = np.random.default_rng(1515)
+    cases = {}
+    for nb in (1, 2, 4, 8, 16, 32, 64, 128):  # every chunk bucket
+        n = nb * rice.BLOCK_VALUES - 3
+        cases[f"bucket{nb}"] = rng.laplace(0, 4.0 * nb, n)
+    cases["all_escape"] = rng.integers(2**29, 2**31, 768) * rng.choice([-1, 1], 768)
+    cases["int32_extremes"] = np.tile([I32_MIN, I32_MAX, 0, -1, I32_MIN + 1], 120)
+    cases["k0"] = rng.integers(-1, 1, 600)
+    cases["k24"] = rng.integers(2**25, 2**26, 768) * rng.choice([-1, 1], 768)
+    # 32 escapes each followed by one 1-bit zero code: the 41-bit strides
+    # start an escape at every bit offset mod 32
+    esc = np.zeros(rice.BLOCK_VALUES, np.int64)
+    esc[0:64:2] = I32_MAX
+    cases["escape_every_offset"] = esc
+    cases["mixed_every_offset"] = np.concatenate(
+        [rng.laplace(0, 2.0 ** rng.integers(0, 22), 64) for _ in range(12)]
+    )
+    return {
+        k: np.clip(np.rint(v), I32_MIN, I32_MAX).astype(np.int32)
+        for k, v in cases.items()
+    }
+
+
+GOLDEN_CASES = _golden_cases()
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(GOLDEN_CASES))
+def test_rice_matches_sequential_writer(case, backend):
+    x = GOLDEN_CASES[case]
+    want, want_k, want_len, offsets = _sequential_rice(x)
+    # the cases cover what they are named for
+    if case == "k0":
+        assert (want_k == 0).all()
+    if case == "k24":
+        assert (want_k == rice.K_MAX).all()
+    if case == "all_escape":
+        assert len(want) == 5 * x.size  # every code 40 bits
+    if case.endswith("every_offset"):
+        assert set((offsets % 32).tolist()) == set(range(32))
+    payload, ks, lens = rice.encode_band(x, backend=backend)
+    assert payload == want
+    np.testing.assert_array_equal(ks, want_k)
+    np.testing.assert_array_equal(lens, want_len)
+
+
+def _digest_band():
+    rng = np.random.default_rng(20150)
+    n = 2 * rice.CHUNK_BLOCKS * rice.BLOCK_VALUES + 777
+    x = rng.laplace(0, 300, n).astype(np.int64)
+    idx = rng.integers(0, n, 400)
+    x[idx] = rng.integers(-(2**31), 2**31, 400)
+    x[:5] = [I32_MIN, I32_MAX, 0, -1, 1]
+    return np.clip(x, I32_MIN, I32_MAX).astype(np.int32)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_rice_frozen_payload_digest(backend):
+    """A seeded band over three chunks codes to the bytes the scatter-
+    and-bit-plane encoder wrote before the shift-or pack replaced it."""
+    payload, ks, lens = rice.encode_band(_digest_band(), backend=backend)
+    assert len(payload) == 90849
+    assert hashlib.sha256(payload).hexdigest() == (
+        "88b3dee0a36aea81eb794c47471af3b0b957ee379b2e37c75d35b0d61aca5363"
+    )
+    assert hashlib.sha256(ks.tobytes() + lens.tobytes()).hexdigest() == (
+        "31ca00e556c29ecc6b6fa0a30b821627113e5fdf98add72e4a819dbb0fc4891c"
     )
 
 

@@ -139,6 +139,16 @@ def test_slab_3d_fwd_inv(one_chip):
     _assert_mosaic(fused3d.inv3d_slab, _bands_3d(1, d, h, w, one_chip), **static)
 
 
-def test_rice_pack_kernel(one_chip):
-    pack = jax.jit(lambda b: rice._pack_words_pallas(b, interpret=False))
-    _assert_mosaic(pack, _spec((rice.CHUNK_BLOCKS, 32, rice._WORDS), one_chip))
+@pytest.mark.parametrize("nb", [1, 16, rice.CHUNK_BLOCKS])
+def test_rice_encode_chunk(one_chip, nb):
+    """The whole compiled chunk encode, at the smallest, a middle and the
+    largest bucket: the pack is a Mosaic kernel and nothing scatters."""
+    text = (
+        rice._encode_chunk.lower(
+            _spec((nb, rice.BLOCK_VALUES), one_chip), pack_backend="pallas"
+        )
+        .compile()
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    assert "scatter" not in text
