@@ -17,6 +17,7 @@ reference (``reference.py``) once the window has closed.
 from __future__ import annotations
 
 import contextlib
+import math
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -170,7 +171,7 @@ def containers_to_check(run: Run) -> Set[int]:
             rows.setdefault(id(rec.blob), set()).add(rec.shape)
     blobs = list(rows)
     order = [blobs[i] for i in data.rng_for(run.seed, 5).permutation(len(blobs))]
-    bucket = max(h * w for h, w in run.config["buckets"]) * run.config["batch_slots"]
+    bucket = max(math.prod(b) for b in run.config["buckets"]) * run.config["batch_slots"]
     picked: Set[int] = set()
     shapes: set = set()
     for b in order:
@@ -189,14 +190,15 @@ def check(run: Run, pool: List[np.ndarray]) -> Dict[str, Dict[str, int]]:
 
     Every request due in the window must be answered.  Ingest: the
     container of each sampled request (:func:`containers_to_check`),
-    decoded by ``reference.py``, must hold its pool image in its row.
+    decoded by ``reference.py`` a group of rows at a time, must hold its
+    pool image in its row, an image or a volume alike.
     Read: each delivered slice must be the series slice it names.
     Returns each compared number with its limit.
     """
     kw = {k: run.config[k] for k in ("levels", "mode", "scheme")}
     sampled = containers_to_check(run)
     wrong = unanswered = checked = 0
-    decoded: Dict[int, Any] = {}
+    rows: Dict[int, tuple] = {}  # a sampled container and its (row, image) pairs
     for rec in run.records:
         img = pool[rec.pool_index]
         if not rec.answered:
@@ -205,10 +207,10 @@ def check(run: Run, pool: List[np.ndarray]) -> Dict[str, Dict[str, int]]:
             wrong += reference.mismatches(np.asarray(rec.delivered), None, img)
             checked += 1
         elif id(rec.blob) in sampled:
-            if id(rec.blob) not in decoded:  # a batch container decodes once
-                decoded[id(rec.blob)] = reference.decode_or_none(rec.blob, **kw)
-            wrong += reference.mismatches(decoded[id(rec.blob)], rec.batch_index, img)
+            rows.setdefault(id(rec.blob), (rec.blob, []))[1].append((rec.batch_index, img))
             checked += 1
+    for blob, pairs in rows.values():  # a batch container decodes once
+        wrong += reference.container_mismatches(blob, pairs, **kw)
     return {
         "mismatched_samples": {"value": wrong, "limit": 0},
         "unanswered": {"value": unanswered, "limit": 0},
