@@ -436,17 +436,22 @@ def _pick_tile(h: int, w: int, halo: int, _state) -> Tuple[int, int]:
 
 # ---------------------------------------------------------------------------
 # Slab policy for the fused 3D engine (kernels/fused3d.py): volumes past
-# the whole-volume budget are blocked along the DEPTH axis only — a slab
-# of TD depth slices plus the scheme's reflect halo, with H and W kept
-# fully resident per slab (the plane axes run the exact band-policy
-# math, so any registered scheme works along them; only the slab axis
-# needs windowability).
+# the whole-volume budget are blocked along the DEPTH axis — a slab of TD
+# depth slices plus the scheme's reflect halo.  Where even the smallest
+# slab of whole planes is over budget (a 512x512 plane at 16 MiB), the
+# plane is tiled along H as well, in TH-row tiles with the same halo; W
+# always stays whole, so axis -1 runs the exact band-policy math and any
+# registered scheme works along it.
 # ---------------------------------------------------------------------------
 
 _SLAB_ENV = "REPRO_DWT_SLAB"
 
 DEFAULT_SLAB = 8  # depth slices per slab core; shrunk to fit the budget
 _MIN_SLAB = 2  # slabs are even and >= 2 so every window has a full halo
+# H tiles are multiples of 16 rows: each band block of a tile is then
+# (TH/2, W/2) with TH/2 a multiple of 8, as Mosaic requires of a block
+# that is not the whole array (W/2 is the whole axis)
+SLAB_TILE_ROWS = 16
 
 
 def slab_forced() -> bool:
@@ -471,33 +476,53 @@ def _slab_env_override() -> Optional[int]:
     return td
 
 
-def pick_slab(d: int, h: int, w: int, halo: int = 2) -> int:
-    """Core slab depth TD for a (d, h, w) volume under the 3D budget.
+def pick_slab(
+    d: int, h: int, w: int, halo: int = 2, tile_h: bool = True
+) -> Optional[Tuple[int, Optional[int]]]:
+    """Core ``(TD, TH)`` of the slab kernel's windows for a (d, h, w)
+    volume, or ``None`` where no window fits the 3D budget.
 
-    Even, >= ``_MIN_SLAB``, sized so the halo'd (TD + 2*halo, H, W) slab
-    windows (the dominant resident buffers of the slab kernel) fit the
-    derived budget.  ``REPRO_DWT_SLAB`` overrides.
+    ``TH`` is ``None`` when a window of whole planes fits: ``TD`` is then
+    the largest even depth up to ``DEFAULT_SLAB`` whose (TD + 2*halo, H,
+    W) window fits.  Otherwise, where ``tile_h`` (the scheme can window
+    the H axis), the plane is tiled along H too: windows are (TD +
+    2*halo, TH + 2*halo, W), TH a multiple of ``SLAB_TILE_ROWS``, and the
+    pair chosen is the one that gathers the fewest window samples over
+    the whole volume (then the fewest grid cells).  ``REPRO_DWT_SLAB``
+    sets TD with whole planes, whatever the budget.
     """
-    return _pick_slab(d, h, w, halo, dispatch_state())
+    return _pick_slab(d, h, w, halo, tile_h, dispatch_state())
 
 
 @functools.lru_cache(maxsize=4096)
-def _pick_slab(d: int, h: int, w: int, halo: int, _state) -> int:
+def _pick_slab(
+    d: int, h: int, w: int, halo: int, tile_h: bool, _state
+) -> Optional[Tuple[int, Optional[int]]]:
     override = _slab_env_override()
     if override is not None:
-        return override
+        return override, None  # explicit override: the operator owns the budget
     budget = fused3d_budget_elems()
-    td = DEFAULT_SLAB
-    while (td + 2 * halo) * h * w > budget and td > _MIN_SLAB:
-        td = max(td - 2, _MIN_SLAB)
-    # never slab beyond the volume (ceil to even: odd depth pads one slice)
-    td = min(td, d + (d % 2))
-    return max(td, _MIN_SLAB)
+    depth = d + d % 2  # never slab beyond the volume (odd depth pads one slice)
+    if (_MIN_SLAB + 2 * halo) * h * w <= budget:
+        td = DEFAULT_SLAB
+        while (td + 2 * halo) * h * w > budget and td > _MIN_SLAB:
+            td = max(td - 2, _MIN_SLAB)
+        return max(min(td, depth), _MIN_SLAB), None
+    if not tile_h:
+        return None
 
+    def cost(td: int, th: int) -> Tuple[int, int]:
+        n_slabs = -(-(d - d // 2) // (td // 2))
+        n_tiles = -(-(h - h // 2) // (th // 2))
+        return (
+            n_slabs * (td + 2 * halo) * n_tiles * (th + 2 * halo),
+            n_slabs * n_tiles,
+        )
 
-def slab_fits(h: int, w: int, halo: int = 2) -> bool:
-    """True when even the minimal slab window fits the 3D budget — the
-    feasibility half of the slab-vs-XLA fallback decision."""
-    if _slab_env_override() is not None:
-        return True  # explicit override: the operator owns the budget
-    return (_MIN_SLAB + 2 * halo) * h * w <= fused3d_budget_elems()
+    fits = [
+        (td, th)
+        for td in range(_MIN_SLAB, min(DEFAULT_SLAB, depth) + 1, 2)
+        for th in range(SLAB_TILE_ROWS, h, SLAB_TILE_ROWS)
+        if (td + 2 * halo) * (th + 2 * halo) * w <= budget
+    ]
+    return min(fits, key=lambda t: cost(*t)) if fits else None

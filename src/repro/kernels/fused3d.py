@@ -19,20 +19,28 @@ stack past the hardcoded 1D/2D entry points:
     (windowability not required).
   * Slab-tiled kernel for volumes past the derived VMEM budget
     (``backend.fused3d_budget_elems``): the volume is blocked along the
-    DEPTH axis only — slabs of TD slices extended by the scheme's
-    reflect halo (``scheme.halo``, mirroring ``kernels/tiled2d.py``'s
-    windows), H and W fully resident per slab.  The plane axes run the
-    exact band-policy math per depth slice (any scheme), and the slab
-    axis runs the interior window math
-    (``schemes.lift_{fwd,inv}_axis_ext``), so only the DEPTH axis needs
-    ``scheme.can_window``.  Correctness rests on the tiled2d identity:
-    for reflection-commuting schemes the reference's whole boundary
-    policy IS whole-point reflect extension of the input, and per-slice
-    plane transforms commute with depth reflection trivially.
-  * Volumes that neither fit the budget nor can slab (degenerate planes
-    bigger than the budget, unwindowable depth) degrade to the
+    DEPTH axis — slabs of TD slices extended by the scheme's reflect
+    halo (``scheme.halo``, mirroring ``kernels/tiled2d.py``'s windows).
+    Where even the smallest slab of whole planes is over budget (a
+    512x512 plane at v5e's 16 MiB), the plane is tiled along H as well:
+    the grid is (batch, slab, H tile) and each cell holds a (TD + 2*halo,
+    TH + 2*halo, W) window, reflect-halo'd on depth and H, W whole
+    (``backend.pick_slab`` sizes TD and TH).  Axis -1 always runs the
+    exact band-policy math (any scheme); axis -2 runs it too on whole
+    planes and the interior window math on H tiles; the slab axis runs
+    the interior window math (``schemes.lift_{fwd,inv}_axis_ext``).  So
+    the depth axis needs ``scheme.can_window``, and H does where it is
+    tiled.  Correctness rests on the tiled2d identity: for
+    reflection-commuting schemes the reference's whole boundary policy
+    IS whole-point reflect extension of the input, and axis -1 mixes no
+    rows or slices, so it commutes with the depth and H windows.
+  * Volumes that neither fit the budget nor can slab degrade to the
     unbounded, bit-exact XLA path with a one-time
-    ``BackendDegradeWarning`` — never a silent cliff.
+    ``BackendDegradeWarning`` — never a silent cliff.  They are the
+    volumes whose scheme cannot window depth (``cdf22`` anywhere, haar
+    on odd depth), or cannot window an over-budget plane's H (the same
+    schemes), and planes so wide that a window of the smallest slab and
+    H tile is still over budget (W > 1747 for cdf53 at 16 MiB).
 
 Multi-level: ``dwt_fwd_nd``/``dwt_inv_nd`` fuse the full N-D Mallat
 pyramid into one compiled dispatch on the Pallas engine (per-level
@@ -50,6 +58,7 @@ import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
@@ -61,6 +70,7 @@ from repro.kernels import backend as _backend
 from repro.kernels import fused2d as _f2d
 from repro.kernels import ops as _ops
 from repro.kernels.ops import _compute_dtype
+from repro.kernels.tiled2d import _ceil_to, _win_rows
 
 Array = jax.Array
 
@@ -158,21 +168,18 @@ def _inv3d_pallas(bands: Tuple[Array, ...], scheme, mode: str, interpret: bool):
 
 
 # ---------------------------------------------------------------------------
-# Slab-tiled Pallas kernel: depth-blocked halo windows, planes resident.
-# The plane axes run the exact band-policy cascade per depth slice (the
-# reference's own composition order: -1 then -2); the slab axis runs
-# interior window math on the reflect-extended depth streams.
+# Slab-tiled Pallas kernel: depth-blocked halo windows, each of whole
+# planes or, where a plane is over budget, of one H tile of it.  Axis -1
+# (W, always whole) runs the exact band-policy math; axis -2 runs it too
+# on whole planes and the interior window math on H tiles; the slab axis
+# always runs the interior window math on the reflect-extended streams.
 # ---------------------------------------------------------------------------
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
 
 
 def _fwd_slab_kernel(*refs, scheme, mode: str, hw):
     win_refs, band_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
     bands = S.lift_fwd_split(
-        [r[0, 0] for r in win_refs], scheme, mode, (hw[1], hw[0], None)
+        [r[0, 0, 0] for r in win_refs], scheme, mode, (hw[1], hw[0], None)
     )
     for ref, b in zip(band_refs, bands):
         ref[0] = b
@@ -181,126 +188,173 @@ def _fwd_slab_kernel(*refs, scheme, mode: str, hw):
 def _inv_slab_kernel(*refs, scheme, mode: str, hw):
     band_refs, comp_refs = refs[:_N_BANDS_3D], refs[_N_BANDS_3D:]
     comps = S.lift_inv_split(
-        [r[0, 0] for r in band_refs], scheme, mode, (hw[1], hw[0], None)
+        [r[0, 0, 0] for r in band_refs], scheme, mode, (hw[1], hw[0], None)
     )
     for ref, c in zip(comp_refs, comps):
         ref[0] = c
 
 
-def _slab_win_spec(wd: int, h: int, w: int):
-    """One (1,1,wd,h,w) depth window per (b, i) grid cell."""
-    return pl.BlockSpec((1, 1, wd, h, w), lambda b, i: (b, i, 0, 0, 0))
+def _slab_win_spec(wd: int, wh: int, w: int):
+    """One (1,1,1,wd,wh,w) window per (b, slab, tile) grid cell."""
+    return pl.BlockSpec(
+        (1, 1, 1, wd, wh, w), lambda b, i, j: (b, i, j, 0, 0, 0)
+    )
 
 
-def _slab_out_spec(bd: int, h: int, w: int):
-    """A (1,bd,h,w) depth block of a (B, n*bd, h, w) output per cell."""
-    return pl.BlockSpec((1, bd, h, w), lambda b, i: (b, i, 0, 0))
+def _slab_out_spec(bd: int, bh: int, w: int):
+    """A (1,bd,bh,w) block of a (B, n_slabs*bd, n_tiles*bh, w) output."""
+    return pl.BlockSpec((1, bd, bh, w), lambda b, i, j: (b, i, j, 0))
 
 
-def _depth_windows(x: Array, rows: np.ndarray) -> Array:
-    """(B, D', H, W) -> (B, n_slabs, wd, H, W) overlapping depth windows."""
-    return x[:, rows]
+def _slab_windows(
+    x: Array, rows: np.ndarray, cols: Optional[np.ndarray]
+) -> Array:
+    """(B, D', H', W') -> (B, n_slabs, n_tiles, wd, wh, W') overlapping
+    windows: depth rows ``rows`` and H rows ``cols`` (``None``: the whole
+    plane, one tile)."""
+    win = x[:, rows]  # (B, n_slabs, wd, H', W')
+    if cols is None:
+        return win[:, :, None]
+    return jnp.transpose(win[:, :, :, cols], (0, 1, 3, 2, 4, 5))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scheme", "mode", "td", "interpret")
+    jax.jit, static_argnames=("scheme", "mode", "td", "th", "interpret")
 )
 def fwd3d_slab(
-    x: Array, mode: str, td: int, interpret: bool, scheme="cdf53"
+    x: Array, mode: str, td: int, interpret: bool, scheme="cdf53",
+    th: Optional[int] = None,
 ):
     """Slab-tiled forward 3D level over a (B, D, H, W) batch.
 
-    Returns the 8 code-ordered bands with the reference shapes.
-    Bit-exact vs ``core.lifting.dwt_fwd_nd`` for every scheme/shape the
-    dispatcher routes here (``scheme.can_window(D)``).
+    Windows of ``td`` depth slices and, with ``th``, of ``th`` rows of
+    the plane (``None``: whole planes), each extended by the scheme's
+    reflect halo.  Returns the 8 code-ordered bands with the reference
+    shapes.  Bit-exact vs ``core.lifting.dwt_fwd_nd`` for every
+    scheme/shape the dispatcher routes here (``scheme.can_window`` along
+    D, and along H with ``th``).
     """
     sch = S.get_scheme(scheme)
     halo = sch.halo
     m = sch.fwd_margin
     bsz, d, h, w = x.shape
     dims = _band_dims_3d(d, h, w)
-    d_e = d - d // 2
     bd = td // 2
-    n_slabs = _ceil_to(d_e, bd) // bd
-    rows = np.stack(
-        [
-            S.reflect_indices(t * td - halo, td + 2 * halo, d)
-            for t in range(n_slabs)
-        ]
+    n_slabs = _ceil_to(d - d // 2, bd) // bd
+    # windows start on an even sample (halo = 2*fwd_margin): the stride-2
+    # columns of a map are the even / odd samples of its axis
+    rows = _win_rows(
+        n_slabs, td, halo, lambda s, c: S.reflect_indices(s, c, d)
     )
-    # windows start on an even slice (halo = 2*fwd_margin): the stride-2
-    # rows of the map are the depth-even / depth-odd slices
-    planes = S.polyphase_split(x, 2)
+    if th is None:
+        n_tiles, cols = 1, None
+        comps = S.polyphase_split(x, 2)  # H and W split; depth windowed
+    else:
+        bh = th // 2
+        n_tiles = _ceil_to(h - h // 2, bh) // bh
+        cols = _win_rows(
+            n_tiles, th, halo, lambda s, c: S.reflect_indices(s, c, h)
+        )
+        comps = S.polyphase_split(x, 1)  # W split; depth and H windowed
     wins = [
-        _depth_windows(planes[code & 3], rows[:, (code >> 2) & 1 :: 2])
+        _slab_windows(
+            comps[code & (3 if th is None else 1)],
+            rows[:, (code >> 2) & 1 :: 2],
+            None if th is None else cols[:, (code >> 1) & 1 :: 2],
+        )
         for code in range(_N_BANDS_3D)
     ]
+    # a band block's H extent: the band's whole H on whole planes, the
+    # tile's core rows on tiles
+    bhs = [dim[1] if th is None else th // 2 for dim in dims]
     bands = pl.pallas_call(
-        functools.partial(_fwd_slab_kernel, scheme=sch, mode=mode, hw=(h, w)),
-        grid=(bsz, n_slabs),
-        in_specs=[
-            _slab_win_spec(bd + 2 * m, *dim[1:]) for dim in dims
-        ],
-        out_specs=tuple(_slab_out_spec(bd, *dim[1:]) for dim in dims),
+        functools.partial(
+            _fwd_slab_kernel, scheme=sch, mode=mode,
+            hw=(h if th is None else None, w),
+        ),
+        grid=(bsz, n_slabs, n_tiles),
+        in_specs=[_slab_win_spec(*win.shape[3:]) for win in wins],
+        out_specs=tuple(
+            _slab_out_spec(bd, bh, dim[2]) for bh, dim in zip(bhs, dims)
+        ),
         out_shape=tuple(
-            jax.ShapeDtypeStruct((bsz, n_slabs * bd) + dim[1:], x.dtype)
-            for dim in dims
+            jax.ShapeDtypeStruct(
+                (bsz, n_slabs * bd, n_tiles * bh, dim[2]), x.dtype
+            )
+            for bh, dim in zip(bhs, dims)
         ),
         interpret=interpret,
     )(*wins)
-    return tuple(b[:, : dim[0]] for b, dim in zip(bands, dims))
+    return tuple(b[:, : dim[0], : dim[1]] for b, dim in zip(bands, dims))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scheme", "mode", "td", "interpret")
+    jax.jit, static_argnames=("scheme", "mode", "td", "th", "interpret")
 )
 def inv3d_slab(
     bands: Tuple[Array, ...], mode: str, td: int, interpret: bool,
-    scheme="cdf53",
+    scheme="cdf53", th: Optional[int] = None,
 ):
-    """Slab-tiled inverse of :func:`fwd3d_slab`."""
+    """Slab-tiled inverse of :func:`fwd3d_slab` (the same ``td``/``th``)."""
     sch = S.get_scheme(scheme)
     m = sch.inv_margin
     bsz = bands[0].shape[0]
     d = bands[0].shape[1] + bands[4].shape[1]
     h = bands[0].shape[2] + bands[2].shape[2]
     w = bands[0].shape[3] + bands[1].shape[3]
-    d_e = d - d // 2
+    dims = _band_dims_3d(d, h, w)
     me = td // 2
-    n_slabs = _ceil_to(d_e, me) // me
-    # band-entry depth windows per polyphase role: codes 0-3 are the
-    # depth-even (s) stream, codes 4-7 the depth-odd (d) stream; every
-    # window entry is an exact policy extension (schemes.reflect_entries)
-    idx = {
-        parity: np.stack(
-            [
-                S.reflect_entries(t * me - m, me + 2 * m, parity, d)
-                for t in range(n_slabs)
-            ]
-        )
-        for parity in (0, 1)
-    }
+    n_slabs = _ceil_to(d - d // 2, me) // me
+
+    # band-entry window maps per polyphase role: codes with the axis's bit
+    # clear are its even (s) stream, codes with it set its odd (d) stream;
+    # every window entry is an exact policy extension
+    # (schemes.reflect_entries)
+    def entries(n_win: int, core: int, n: int):
+        return {
+            parity: _win_rows(
+                n_win, core, m,
+                lambda s, c: S.reflect_entries(s, c, parity, n),
+            )
+            for parity in (0, 1)
+        }
+
+    rows = entries(n_slabs, me, d)
+    if th is None:
+        n_tiles, cols, bhs = 1, None, [dim[1] for dim in dims]
+    else:
+        bh = th // 2
+        n_tiles = _ceil_to(h - h // 2, bh) // bh
+        cols, bhs = entries(n_tiles, bh, h), [bh] * _N_BANDS_3D
     wins = tuple(
-        _depth_windows(b, idx[(code >> 2) & 1])
+        _slab_windows(
+            b, rows[(code >> 2) & 1],
+            None if cols is None else cols[(code >> 1) & 1],
+        )
         for code, b in enumerate(bands)
     )
-    dims = _band_dims_3d(d, h, w)
     comps = pl.pallas_call(
-        functools.partial(_inv_slab_kernel, scheme=sch, mode=mode, hw=(h, w)),
-        grid=(bsz, n_slabs),
-        in_specs=[
-            _slab_win_spec(me + 2 * m, *dims[code][1:])
-            for code in range(_N_BANDS_3D)
-        ],
-        out_specs=tuple(_slab_out_spec(me, *dim[1:]) for dim in dims),
+        functools.partial(
+            _inv_slab_kernel, scheme=sch, mode=mode,
+            hw=(h if th is None else None, w),
+        ),
+        grid=(bsz, n_slabs, n_tiles),
+        in_specs=[_slab_win_spec(*win.shape[3:]) for win in wins],
+        out_specs=tuple(
+            _slab_out_spec(me, bh, dim[2]) for bh, dim in zip(bhs, dims)
+        ),
         out_shape=tuple(
-            jax.ShapeDtypeStruct((bsz, n_slabs * me) + dim[1:], bands[0].dtype)
-            for dim in dims
+            jax.ShapeDtypeStruct(
+                (bsz, n_slabs * me, n_tiles * bh, dim[2]), bands[0].dtype
+            )
+            for bh, dim in zip(bhs, dims)
         ),
         interpret=interpret,
     )(*wins)
-    x = S.polyphase_merge(list(comps), (n_slabs * td, h, w))
-    return x[:, :d]
+    x = S.polyphase_merge(
+        list(comps), (n_slabs * td, h if th is None else n_tiles * th, w)
+    )
+    return x[:, :d, :h]
 
 
 # ---------------------------------------------------------------------------
@@ -323,27 +377,36 @@ def _fits_vmem3(d: int, h: int, w: int) -> bool:
     return d * h * w <= _backend.fused3d_budget_elems()
 
 
-def _can_slab(d: int, h: int, w: int, scheme) -> bool:
-    # only the slab (depth) axis needs the windowed dataflow; the plane
-    # axes run exact band-policy math inside the kernel, so any scheme
-    # works along H/W — but the slab windows themselves must fit VMEM
+# a level's slab windows, (td, th): depth slices and H rows per core
+SlabCore = Optional[Tuple[int, Optional[int]]]
+
+
+def _slab_core(d: int, h: int, w: int, scheme) -> SlabCore:
+    """The slab kernel's ``(td, th)`` for a (d, h, w) level, or ``None``
+    where it cannot take it.  The slab axis always runs the windowed
+    dataflow and so needs ``scheme.can_window(d)``; H does only when the
+    plane is tiled; W runs exact band-policy math (any scheme)."""
     sch = S.get_scheme(scheme)
-    return sch.can_window(d) and _backend.slab_fits(h, w, sch.halo)
+    if not sch.can_window(d):
+        return None
+    return _backend.pick_slab(d, h, w, sch.halo, tile_h=sch.can_window(h))
 
 
-def _use_slab(d: int, h: int, w: int, scheme) -> bool:
-    return _can_slab(d, h, w, scheme) and (
-        _backend.slab_forced() or not _fits_vmem3(d, h, w)
-    )
+def _use_slab(d: int, h: int, w: int, scheme) -> SlabCore:
+    """``_slab_core`` where the level takes the slab kernel, else None."""
+    if _fits_vmem3(d, h, w) and not _backend.slab_forced():
+        return None
+    return _slab_core(d, h, w, scheme)
 
 
 def _fwd3d_level(x4: Array, scheme, mode: str, interpret: bool):
     """One forward level on a (B, D, H, W) compute-dtype batch
     (trace-time whole-volume/slab choice; both are Pallas)."""
     d, h, w = x4.shape[-3:]
-    if _use_slab(d, h, w, scheme):
-        td = _backend.pick_slab(d, h, w, S.get_scheme(scheme).halo)
-        return fwd3d_slab(x4, mode, td, interpret, scheme=scheme)
+    core = _use_slab(d, h, w, scheme)
+    if core is not None:
+        td, th = core
+        return fwd3d_slab(x4, mode, td, interpret, scheme=scheme, th=th)
     if _fits_vmem3(d, h, w):
         return _fwd3d_pallas(x4, scheme=scheme, mode=mode, interpret=interpret)
     # over budget but un-slab-able: in-graph jnp math — never a
@@ -355,9 +418,10 @@ def _inv3d_level(bands, scheme, mode: str, interpret: bool):
     d = bands[0].shape[-3] + bands[4].shape[-3]
     h = bands[0].shape[-2] + bands[2].shape[-2]
     w = bands[0].shape[-1] + bands[1].shape[-1]
-    if _use_slab(d, h, w, scheme):
-        td = _backend.pick_slab(d, h, w, S.get_scheme(scheme).halo)
-        return inv3d_slab(tuple(bands), mode, td, interpret, scheme=scheme)
+    core = _use_slab(d, h, w, scheme)
+    if core is not None:
+        td, th = core
+        return inv3d_slab(tuple(bands), mode, td, interpret, scheme=scheme, th=th)
     if _fits_vmem3(d, h, w):
         return _inv3d_pallas(
             tuple(bands), scheme=scheme, mode=mode, interpret=interpret
@@ -370,15 +434,50 @@ def _resolve_3d(
 ) -> str:
     """Backend for a 3D transform; names the one remaining budget cliff."""
     b = _backend.resolve(backend)
-    if b != "xla" and not _fits_vmem3(d, h, w) and not _can_slab(d, h, w, scheme):
+    if (
+        b != "xla"
+        and not _fits_vmem3(d, h, w)
+        and _slab_core(d, h, w, scheme) is None
+    ):
         _backend.note_degrade(
             b, "xla",
             f"budget: ({d}, {h}, {w}) exceeds the whole-volume VMEM budget "
             f"and scheme {S.get_scheme(scheme).name!r} cannot take the "
-            "depth-slab path there",
+            "slab path there (no depth slab or H tile of whole rows fits, "
+            "or the scheme cannot window an axis it would tile)",
         )
         return "xla"
     return b
+
+
+def plan_3d_levels(
+    shape: Tuple[int, int, int], levels: int, backend: Optional[str] = None,
+    scheme="cdf53",
+) -> Tuple[str, ...]:
+    """The path of each level of a ``levels``-deep 3D pyramid over a
+    (d, h, w) volume, finest first, as :func:`plan_3d` names them.
+
+    The backend is resolved once, at the finest level, as
+    :func:`dwt_fwd_nd` does; each level then takes the whole-volume or
+    the slab kernel from its own shape, or in-graph XLA math where it
+    can take neither.
+    """
+    sch = S.get_scheme(scheme)
+    d, h, w = shape
+    b = _resolve_3d(backend, d, h, w, sch)
+    kind = "interpret" if b == "interpret" else "pallas"
+    out = []
+    for _ in range(levels):
+        if b == "xla":
+            out.append("xla")
+        elif _use_slab(d, h, w, sch) is not None:
+            out.append(f"slab-{kind}")
+        elif _fits_vmem3(d, h, w):
+            out.append(f"whole-{kind}")
+        else:
+            out.append("xla")
+        d, h, w = d - d // 2, h - h // 2, w - w // 2
+    return tuple(out)
 
 
 def plan_3d(
@@ -391,12 +490,7 @@ def plan_3d(
     (``benchmarks/gate.py``) use this to assert budget-sized volumes
     never silently leave the Pallas path on an accelerator.
     """
-    sch = S.get_scheme(scheme)
-    b = _resolve_3d(backend, d, h, w, sch)
-    if b == "xla":
-        return "xla"
-    kind = "slab" if _use_slab(d, h, w, sch) else "whole"
-    return f"{kind}-{'interpret' if b == 'interpret' else 'pallas'}"
+    return plan_3d_levels((d, h, w), 1, backend, scheme)[0]
 
 
 # ---------------------------------------------------------------------------

@@ -33,6 +33,7 @@ watchdog (PR 6), which must stay outside any trace.  Its inner
 """
 from __future__ import annotations
 
+import itertools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import backend as _backend
+from repro.kernels import fused3d
 
 Shape = Tuple[int, ...]
 
@@ -63,11 +65,21 @@ def mesh_signature(mesh: Optional[Any]) -> Optional[Tuple[Tuple[str, int], ...]]
     return tuple((str(k), int(v)) for k, v in dict(mesh.shape).items())
 
 
+def plan_label(plans) -> str:
+    """Per-level paths as runs, finest first:
+    ``("slab-pallas",) * 3 + ("whole-pallas",) * 2`` ->
+    ``"slab-pallas×3,whole-pallas×2"``."""
+    return ",".join(f"{p}×{len(list(g))}" for p, g in itertools.groupby(plans))
+
+
 class TransformExecutor:
     """One compiled forward-transform executable per :class:`ExecKey`."""
 
     def __init__(self):
         self._cache: Dict[ExecKey, Callable] = {}
+        # a volume bucket's per-level transform paths (plan_label), worked
+        # out once when its executable is built
+        self._plans: Dict[ExecKey, str] = {}
         self._traces = 0  # times a cached executable's Python body ran
         self.hits = 0
         self.misses = 0
@@ -109,12 +121,21 @@ class TransformExecutor:
             return sharded_fn
 
         if len(key.bucket) == 3:
-            def transform(batch, _key=key):
+            # its own name, so a device trace tells the volume's program
+            # (``jit_transform_3d``) from the 2-D one (``jit_transform``)
+            def transform_3d(batch, _key=key):
                 self._traces += 1
                 return K.dwt_fwd_nd(
                     batch, levels=_key.levels, mode=_key.mode,
                     backend=_key.backend, scheme=_key.scheme, ndim=3,
                 )
+
+            transform = transform_3d
+            self._plans[key] = plan_label(
+                fused3d.plan_3d_levels(
+                    key.bucket, key.levels, key.backend, key.scheme
+                )
+            )
         else:
             def transform(batch, _key=key):
                 self._traces += 1
@@ -153,10 +174,16 @@ class TransformExecutor:
         The ``serve.transform`` span is the transform's dispatch time on
         the host: the call returns before the device finishes, and no
         sync is added, so the device's part shows up wherever the caller
-        first blocks on the result.
+        first blocks on the result.  A volume bucket's span also carries
+        ``plan``, its per-level transform paths, and each run counts one
+        ``serve.transform_plan{plan=...}``.
         """
-        bucket = "x".join(str(s) for s in key.bucket)
-        with obs.span("serve.transform", subsystem="serve", bucket=bucket):
+        attrs = {"bucket": "x".join(str(s) for s in key.bucket)}
+        plan = self._plans.get(key)
+        if plan is not None:
+            attrs["plan"] = plan
+            obs.counter("serve.transform_plan", plan=plan).inc()
+        with obs.span("serve.transform", subsystem="serve", **attrs):
             return fn(batch)
 
     def warmup(self, keys, mesh: Optional[Any] = None) -> int:

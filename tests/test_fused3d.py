@@ -8,6 +8,7 @@ import pytest
 
 from repro import kernels as K
 from repro.core import lifting as L
+from repro.kernels import backend as B
 from repro.kernels import fused3d
 
 RNG = np.random.default_rng(11)
@@ -90,6 +91,50 @@ def test_forced_slab_path(monkeypatch, scheme, shape):
         )
         _assert_pyr_equal(got, want)
         xr = K.dwt_inv_nd(got, mode=mode, scheme=scheme, backend="interpret")
+        np.testing.assert_array_equal(np.asarray(xr), np.asarray(x))
+
+
+# (scheme, (D, H, W)) volumes whose plane is over a 0.01 MiB budget (the
+# floored 4096 samples) even in the smallest slab of whole planes, so the
+# slab kernel tiles H as well; odd D, H and W, and one shape (9, 37, 21)
+# with three slabs of three H tiles (haar windows even axes only)
+PLANE_TILED = [
+    ("cdf53", (9, 37, 21)),
+    ("cdf53", (7, 50, 19)),
+    ("97m", (12, 40, 16)),
+    ("97m", (7, 41, 17)),
+    ("haar", (8, 64, 37)),
+]
+
+
+@pytest.mark.parametrize("scheme,shape", PLANE_TILED)
+def test_plane_tiled_slab_path(monkeypatch, scheme, shape):
+    """A small REPRO_DWT_VMEM_MB puts the plane over the smallest slab of
+    whole planes: the slab kernel tiles H too (windows of TD + 2*halo
+    slices by TH + 2*halo rows, W whole), and forward and inverse stay
+    bit-exact vs the oracle in both modes."""
+    monkeypatch.setenv("REPRO_DWT_VMEM_MB", "0.01")
+    d, h, w = shape
+    sch = K.get_scheme(scheme)
+    assert d * h * w > B.fused3d_budget_elems()
+    assert (2 + 2 * sch.halo) * h * w > B.fused3d_budget_elems()
+    td, th = B.pick_slab(d, h, w, sch.halo, tile_h=sch.can_window(h))
+    assert th is not None and th % B.SLAB_TILE_ROWS == 0
+    assert fused3d.plan_3d(*shape, backend="interpret", scheme=scheme) == (
+        "slab-interpret"
+    )
+    x = _vol(2, *shape)
+    for mode in ("paper", "jpeg2000"):
+        want = L.dwt_fwd_nd(x, levels=1, mode=mode, scheme=scheme, ndim=3)
+        got = K.dwt_fwd_nd(
+            x, levels=1, mode=mode, scheme=scheme, ndim=3, backend="interpret"
+        )
+        _assert_pyr_equal(got, want)
+        np.testing.assert_array_equal(
+            np.asarray(L.dwt_inv_nd(want, mode=mode, scheme=scheme)),
+            np.asarray(x),
+        )
+        xr = K.dwt_inv_nd(want, mode=mode, scheme=scheme, backend="interpret")
         np.testing.assert_array_equal(np.asarray(xr), np.asarray(x))
 
 
@@ -197,6 +242,12 @@ def test_plan_3d_names_paths(monkeypatch):
         == f"slab-{kind}"
     )
     assert fused3d.plan_3d(17, 16, 16, backend="pallas", scheme="cdf22") == "xla"
+    # a plane over the budget is tiled along H only where the scheme can
+    # window H: haar cannot on odd H, so that volume stays on the cliff
+    assert fused3d.plan_3d(8, 64, 37, backend="pallas", scheme="haar") == (
+        f"slab-{kind}"
+    )
+    assert fused3d.plan_3d(8, 63, 37, backend="pallas", scheme="haar") == "xla"
 
 
 def test_levels_validation():

@@ -228,6 +228,57 @@ def test_serve_retry_path_counts_attempts_and_events():
     assert len(heals) == 1 and heals[0].mechanism == "retry"
 
 
+@pytest.mark.parametrize("bucket", [(8, 16, 16), (16, 16)])
+def test_serve_transform_span_names_a_volume_plan(bucket):
+    """A volume bucket's ``serve.transform`` span carries ``plan`` (its
+    per-level paths, worked out once at build), each step counts one
+    ``serve.transform_plan{plan=...}`` and the device program is
+    ``jit_transform_3d``; a 2-D bucket's span, counters and program name
+    are as they were."""
+    from repro.kernels import fused3d
+    from repro.serve.engine import TransformRequest, WaveletServeEngine
+    from repro.serve.executor import plan_label
+
+    eng = WaveletServeEngine(
+        buckets=[bucket], batch_slots=1, levels=2, backend="interpret"
+    )
+    eng.warmup()
+    rng = np.random.default_rng(2)
+    done = eng.run([
+        TransformRequest(uid=i, image=rng.integers(-100, 100, bucket, np.int32))
+        for i in range(3)
+    ])
+    assert all(r.done for r in done)
+    spans = obs.tracer.spans(name="serve.transform")
+    assert len(spans) == 3
+    plans = {
+        k: v for k, v in obs.registry.snapshot().items()
+        if k.startswith("serve.transform_plan")
+    }
+    (program,) = eng.executor._cache.values()
+    name = program.as_text().split("\n", 1)[0]
+    label = "x".join(str(s) for s in bucket)
+    if len(bucket) == 3:
+        plan = plan_label(fused3d.plan_3d_levels(bucket, 2, "interpret"))
+        assert plan == "whole-interpret×2"
+        assert all(s.args == {"bucket": label, "plan": plan} for s in spans)
+        assert plans == {f'serve.transform_plan{{plan="{plan}"}}': 3.0}
+        assert name.startswith("HloModule jit_transform_3d")
+    else:
+        assert all(s.args == {"bucket": label} for s in spans)
+        assert plans == {}
+        assert name.startswith("HloModule jit_transform,")
+
+
+def test_plan_label_runs_levels_finest_first():
+    from repro.serve.executor import plan_label
+
+    assert plan_label(("slab-pallas",) * 3 + ("whole-pallas",) * 2) == (
+        "slab-pallas×3,whole-pallas×2"
+    )
+    assert plan_label(("xla",) * 5) == "xla×5"
+
+
 # ---------------------------------------------------------------------------
 # Satellite 1: every degrade counts; the warning still fires once.
 # ---------------------------------------------------------------------------

@@ -132,11 +132,63 @@ def test_whole_volume_3d_fwd_inv(one_chip):
 def test_slab_3d_fwd_inv(one_chip):
     d, h, w = 64, 128, 128
     halo = S.get_scheme("cdf53").halo
-    assert B.slab_fits(h, w, halo)
-    td = B.pick_slab(d, h, w, halo)
+    td, th = B.pick_slab(d, h, w, halo)
+    assert th is None  # whole planes: the plane fits the slab window
     static = dict(mode=MODE, td=td, interpret=False, scheme="cdf53")
     _assert_mosaic(fused3d.fwd3d_slab, _spec((1, d, h, w), one_chip), **static)
     _assert_mosaic(fused3d.inv3d_slab, _bands_3d(1, d, h, w, one_chip), **static)
+
+
+# a CT series as the volume archive serves it: 256 slices of 512x512
+CT_SERIES = (256, 512, 512)
+
+
+def _mosaic_calls(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("shape", [CT_SERIES, (64, 512, 512)])
+def test_plan_3d_keeps_512_planes_on_pallas(monkeypatch, shape):
+    """At v5e's 16 MiB budget a 512x512 plane is over the smallest slab
+    of whole planes, so the slab kernel tiles H: the plan a TPU resolves
+    is ``slab-pallas``, not the XLA cliff."""
+    monkeypatch.setattr(B, "platform", lambda: "tpu")
+    assert B.vmem_budget_bytes() == 16 * 1024 * 1024
+    halo = S.get_scheme("cdf53").halo
+    assert B.pick_slab(*shape, halo)[1] is not None  # H is tiled
+    assert fused3d.plan_3d(*shape) == "slab-pallas"
+    assert fused3d.plan_3d(*shape, backend="pallas") == "slab-pallas"
+
+
+def test_serve_volume_levels_5_at_ct_series(one_chip):
+    """The volume serve executable's transform at (1, 256, 512, 512):
+    five fused levels, every one a Mosaic kernel (plane-tiled slabs at
+    the two finest levels), none left to XLA."""
+    levels = 5
+    static = dict(
+        levels=levels, scheme="cdf53", mode=MODE, interpret=False,
+        dispatch=B.dispatch_state(),
+    )
+    text = (
+        fused3d._fwd3d_multi_kernel.lower(_spec((1,) + CT_SERIES, one_chip), **static)
+        .compile()
+        .as_text()
+    )
+    assert _mosaic_calls(text) == levels
+
+
+def test_plane_tiled_slab_inverse_at_ct_series(one_chip):
+    """One level of the inverse on the plane-tiled slab, at the series'
+    finest level."""
+    td, th = B.pick_slab(*CT_SERIES, S.get_scheme("cdf53").halo)
+    assert th is not None
+    static = dict(mode=MODE, td=td, th=th, interpret=False, scheme="cdf53")
+    text = (
+        fused3d.inv3d_slab.lower(_bands_3d(1, *CT_SERIES, one_chip), **static)
+        .compile()
+        .as_text()
+    )
+    assert _mosaic_calls(text) == 1
 
 
 @pytest.mark.parametrize("nb", [1, 16, rice.CHUNK_BLOCKS])
