@@ -26,9 +26,13 @@ independent blocks of ``BLOCK_VALUES`` samples:
     paths are bit-identical.
 
 Blocks are byte-aligned and self-contained (own ``k``, own byte length),
-so decode parallelizes ACROSS blocks: one ``lax.scan`` of
-``BLOCK_VALUES`` steps runs every block in lockstep, resolving each
-step's unary run in O(1) via a precomputed next-zero suffix scan.
+so decode parallelizes ACROSS blocks, every block in lockstep over its
+``BLOCK_VALUES`` values.  :func:`unpack` runs on the same policy: a
+Pallas kernel with blocks on lanes in which each lane reads its codes
+from a window of three stream words (leading ones, shifts and selects;
+no per-bit state), or on the XLA fallback a ``lax.scan`` over each
+block's bit grid that resolves every unary run in O(1) through a
+next-zero suffix scan.  All paths give the same values.
 
 Host-facing entry points (``encode_band`` / ``decode_band``) take and
 return numpy arrays and chunk internally (``CHUNK_BLOCKS`` blocks per
@@ -60,10 +64,10 @@ LMAX = Q_MAX + 32  # longest code: escape (non-escape max is Q_MAX+K_MAX)
 
 BYTES_CAP = BLOCK_VALUES * LMAX // 8  # worst-case encoded bytes per block
 _WORDS = BYTES_CAP // 4  # packed 32-bit words per block (320)
-_LANES = 128  # blocks per pack-kernel tile: one vreg row of lanes
+_LANES = 128  # blocks per kernel tile: one vreg row of lanes
 
-# encode/decode dispatch width: blocks per compiled chunk (one pack-kernel
-# lane tile; bounds the decoder's per-bit workspace)
+# encode/decode dispatch width: blocks per compiled chunk (one kernel lane
+# tile; bounds the XLA decoder's per-bit workspace)
 CHUNK_BLOCKS = 128
 
 
@@ -288,19 +292,120 @@ def _encode_chunk(
 
 
 # ---------------------------------------------------------------------------
-# Compiled per-chunk decode.
+# Word -> value unpacking: the backend-dispatched decode stage.
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def _decode_chunk(byte_mat: jax.Array, ks: jax.Array) -> jax.Array:
-    """Decode (nb, L) byte rows with per-block k -> (nb, BLOCK_VALUES) i32."""
-    nb, nbytes = byte_mat.shape
-    nbits = nbytes * 8
-    lane = jnp.arange(8, dtype=jnp.int32)
+def _view(a: jax.Array, b: jax.Array, s: jax.Array) -> jax.Array:
+    """The 32 bits that start ``s`` (0..31) bits into the word pair ``a``,
+    ``b``.  ``b`` goes in by two shifts that stay in 0..31, so ``s`` 0
+    takes none of it."""
+    lsr = jax.lax.shift_right_logical
+    return jnp.left_shift(a, s) | lsr(lsr(b, 1), 31 - s)
+
+
+def _unpack_kernel(ks_ref, words_ref, out_ref):
+    """Decode one lane tile: blocks on lanes, words and values on rows.
+
+    Each lane carries a three-word window of its stream, the bit offset
+    into the first word and the index of the next word through its values
+    in order.  A code is at most ``LMAX`` (40) bits, so the window holds
+    all of it wherever it starts in the first word, and a value moves the
+    window on by at most two words: fetched for every lane by a select
+    over the word row groups (a row past the block's words reads 0).
+    Value ``i`` lands in output row ``i`` of every lane."""
+    groups, sub, lanes = words_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 0)
+    k = ks_ref[...]
+    k_mask = jnp.left_shift(1, k) - 1
+
+    def fold(a):  # the one row of each lane's column that was selected
+        return functools.reduce(
+            jnp.bitwise_or, [a[r : r + 1] for r in range(sub)]
+        )
+
+    def fetch(widx):  # words widx and widx + 1 of each lane
+        def group(j, acc):
+            blk = words_ref[j]
+            d = rows + sub * j - widx
+            return (
+                jnp.where(d == 0, blk, acc[0]),
+                jnp.where(d == 1, blk, acc[1]),
+            )
+
+        zero = jnp.zeros((sub, lanes), jnp.int32)
+        f0, f1 = jax.lax.fori_loop(0, groups, group, (zero, zero))
+        return fold(f0), fold(f1)
+
+    def value(state):
+        w0, w1, w2, pos, widx = state
+        f0, f1 = fetch(widx)
+        head = _view(w0, w1, pos)
+        q = jnp.minimum(jax.lax.clz(jnp.bitwise_not(head)), Q_MAX)
+        n = q + 1 + k  # length of a code that does not escape: 1..32
+        rem = jax.lax.shift_right_logical(head, jnp.maximum(32 - n, 0))
+        rem = rem & k_mask
+        p = pos + Q_MAX  # an escape's 32 raw bits start here: 8..39
+        hi = p >= 32
+        raw = _view(jnp.where(hi, w1, w0), jnp.where(hi, w2, w1), p & 31)
+        esc = q >= Q_MAX
+        u = jnp.where(esc, raw, jnp.left_shift(q, k) | rem)
+        pos = pos + jnp.where(esc, LMAX, n)
+        s = jnp.right_shift(pos, 5)  # words the window moves on: 0..2
+        one, two = s == 1, s == 2
+        w0, w1, w2 = (
+            jnp.where(one, w1, jnp.where(two, w2, w0)),
+            jnp.where(one, w2, jnp.where(two, f0, w1)),
+            jnp.where(one, f0, jnp.where(two, f1, w2)),
+        )
+        return (w0, w1, w2, pos & 31, widx + s), u
+
+    def step(g, state):
+        blk = jnp.zeros((sub, lanes), jnp.int32)
+        for r in range(sub):
+            state, u = value(state)
+            blk = jnp.where(rows == r, u, blk)
+        out_ref[g] = blk
+        return state
+
+    first = words_ref[0]
+    zero = jnp.zeros((1, lanes), jnp.int32)
+    start = (first[0:1], first[1:2], first[2:3], zero, zero + 3)
+    jax.lax.fori_loop(0, out_ref.shape[0], step, start)
+
+
+def _unpack_pallas(
+    words: jax.Array, ks: jax.Array, interpret: bool
+) -> jax.Array:
+    from jax.experimental import pallas as pl
+
+    nb, nw = words.shape
+    lanes = -(-nb // _LANES) * _LANES  # padded lanes decode zero words
+    rows = -(-nw // 8) * 8  # at least one row group, whole groups only
+    words = jnp.pad(words.T, ((0, rows - nw), (0, lanes - nb)))
+    ks = jnp.pad(ks, (0, lanes - nb)).reshape(1, lanes)
+    out = (BLOCK_VALUES // 8, 8)
+    us = pl.pallas_call(
+        _unpack_kernel,
+        grid=(lanes // _LANES,),
+        in_specs=[
+            pl.BlockSpec((1, _LANES), lambda c: (0, c)),
+            pl.BlockSpec((rows // 8, 8, _LANES), lambda c: (0, 0, c)),
+        ],
+        out_specs=pl.BlockSpec((*out, _LANES), lambda c: (0, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((*out, lanes), jnp.int32),
+        interpret=interpret,
+    )(ks, words.reshape(rows // 8, 8, lanes))
+    return us.reshape(BLOCK_VALUES, lanes)[:, :nb].T
+
+
+def _unpack_xla(words: jax.Array, ks: jax.Array) -> jax.Array:
+    nb, nw = words.shape
+    nbits = nw * 32
+    lane = jnp.arange(32, dtype=jnp.uint32)
     bits = (
-        (jnp.right_shift(byte_mat.astype(jnp.int32)[..., None], 7 - lane)) & 1
-    ).reshape(nb, nbits)
+        jnp.right_shift(words.astype(jnp.uint32)[..., None], 31 - lane) & 1
+    ).astype(jnp.int32).reshape(nb, nbits)
 
     # next-zero-at-or-after: suffix cummin over masked positions resolves
     # every unary run in O(1) per scan step
@@ -343,7 +448,38 @@ def _decode_chunk(byte_mat: jax.Array, ks: jax.Array) -> jax.Array:
 
     off0 = jnp.zeros((nb,), jnp.int32)
     _, us = jax.lax.scan(step, off0, None, length=BLOCK_VALUES)
-    return unzigzag(jnp.swapaxes(us, 0, 1))
+    return jnp.swapaxes(us, 0, 1)
+
+
+def unpack(words: jax.Array, ks: jax.Array, unpack_backend: str) -> jax.Array:
+    """(nb, W) packed int32 words with per-block ``k`` -> (nb,
+    BLOCK_VALUES) uint32 zigzagged values.
+
+    The inverse of :func:`pack_words`, in the same bit order, for any
+    ``W``; the words past a block's stream are 0.  ``unpack_backend`` is
+    a RESOLVED backend name: the Pallas kernel, or on the XLA fallback a
+    ``lax.scan`` over the block's bit grid that resolves each step's
+    unary run through a next-zero suffix scan.  All paths give the same
+    values on every valid stream.
+    """
+    if unpack_backend == "xla":
+        return _unpack_xla(words, ks)
+    us = _unpack_pallas(words, ks, interpret=(unpack_backend == "interpret"))
+    return jax.lax.bitcast_convert_type(us, jnp.uint32)
+
+
+# ---------------------------------------------------------------------------
+# Compiled per-chunk decode.
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("unpack_backend",))
+def _decode_chunk(
+    words: jax.Array, ks: jax.Array, *, unpack_backend: str
+) -> jax.Array:
+    """Decode (nb, W) rows of big-endian stream words with per-block k ->
+    (nb, BLOCK_VALUES) int32."""
+    return unzigzag(unpack(words, ks, unpack_backend))
 
 
 # ---------------------------------------------------------------------------
@@ -424,8 +560,10 @@ def decode_band(
     k_table: np.ndarray,
     byte_lengths: np.ndarray,
     count: int,
+    backend: Optional[str] = None,
 ) -> np.ndarray:
-    """Inverse of :func:`encode_band` -> flat int32 array of ``count``."""
+    """Inverse of :func:`encode_band` -> flat int32 array of ``count``.
+    ``backend`` selects the unpack kernel path (None = policy default)."""
     if count == 0:
         return np.zeros(0, np.int32)
     nb = n_blocks(count)
@@ -443,6 +581,7 @@ def decode_band(
     raw = np.frombuffer(payload, np.uint8)
     offs = np.concatenate([[0], np.cumsum(blens)])
     out = np.zeros(nb * BLOCK_VALUES, np.int32)
+    resolved = B.resolve_backend(backend)
     with obs.span("codec.decode_band", subsystem="codec",
                   chunks=-(-nb // CHUNK_BLOCKS), blocks=nb, wait_s=0.0):
         for start in range(0, nb, CHUNK_BLOCKS):
@@ -455,7 +594,14 @@ def decode_band(
             mat[:rows][mask] = raw[offs[start] : offs[start + rows]]
             kc = np.zeros(bucket, np.int32)
             kc[:rows] = ks[start : start + rows]
-            (dec,) = _to_host(_decode_chunk(jnp.asarray(mat), jnp.asarray(kc)))
+            # big-endian words of the zero-padded rows (maxlen is a power
+            # of two >= 8, so whole words)
+            words = mat.view(">u4").astype(np.uint32).view(np.int32)
+            (dec,) = _to_host(
+                _decode_chunk(
+                    jnp.asarray(words), jnp.asarray(kc), unpack_backend=resolved
+                )
+            )
             out[
                 start * BLOCK_VALUES : start * BLOCK_VALUES + rows * BLOCK_VALUES
             ] = dec[:rows].reshape(-1)

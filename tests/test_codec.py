@@ -172,6 +172,11 @@ def _golden_cases():
     cases["mixed_every_offset"] = np.concatenate(
         [rng.laplace(0, 2.0 ** rng.integers(0, 22), 64) for _ in range(12)]
     )
+    # one all-escape block of BYTES_CAP bytes among narrow ones: the chunk's
+    # byte bucket is the widest, 2048, and its lanes end words apart
+    cap = rng.laplace(0, 3.0, 16 * rice.BLOCK_VALUES)
+    cap[5 * rice.BLOCK_VALUES : 6 * rice.BLOCK_VALUES] = I32_MIN
+    cases["byte_cap"] = cap
     return {
         k: np.clip(np.rint(v), I32_MIN, I32_MAX).astype(np.int32)
         for k, v in cases.items()
@@ -195,10 +200,23 @@ def test_rice_matches_sequential_writer(case, backend):
         assert len(want) == 5 * x.size  # every code 40 bits
     if case.endswith("every_offset"):
         assert set((offsets % 32).tolist()) == set(range(32))
+    if case == "byte_cap":
+        assert want_len.max() == rice.BYTES_CAP
     payload, ks, lens = rice.encode_band(x, backend=backend)
     assert payload == want
     np.testing.assert_array_equal(ks, want_k)
     np.testing.assert_array_equal(lens, want_len)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(GOLDEN_CASES))
+def test_rice_decodes_sequential_writer(case, backend):
+    """Each golden case, as the sequential writer codes it, decodes to
+    itself on every unpack path."""
+    x = GOLDEN_CASES[case]
+    payload, ks, lens, _ = _sequential_rice(x)
+    got = rice.decode_band(payload, ks, lens, x.size, backend=backend)
+    np.testing.assert_array_equal(got, x)
 
 
 def _digest_band():
@@ -223,6 +241,17 @@ def test_rice_frozen_payload_digest(backend):
     assert hashlib.sha256(ks.tobytes() + lens.tobytes()).hexdigest() == (
         "31ca00e556c29ecc6b6fa0a30b821627113e5fdf98add72e4a819dbb0fc4891c"
     )
+
+
+def test_rice_digest_band_decodes_alike_on_every_path():
+    """The frozen-digest band, three chunks with escapes among them,
+    decodes to itself on the XLA scan and on the Pallas kernel alike."""
+    x = _digest_band()
+    payload, ks, lens = rice.encode_band(x)
+    xla = rice.decode_band(payload, ks, lens, x.size, backend="xla")
+    pallas = rice.decode_band(payload, ks, lens, x.size, backend="pallas")
+    np.testing.assert_array_equal(xla, x)
+    np.testing.assert_array_equal(pallas, xla)
 
 
 # ---------------------------------------------------------------------------

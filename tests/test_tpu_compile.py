@@ -204,3 +204,22 @@ def test_rice_encode_chunk(one_chip, nb):
     )
     assert "tpu_custom_call" in text
     assert "scatter" not in text
+
+
+@pytest.mark.parametrize("nbytes", [8, 2048])
+@pytest.mark.parametrize("nb", [1, 16, rice.CHUNK_BLOCKS])
+def test_rice_decode_chunk(one_chip, nb, nbytes):
+    """The whole compiled chunk decode, at the smallest, a middle and the
+    largest row bucket, for the narrowest and the widest byte bucket: the
+    unpack is a Mosaic kernel and nothing gathers."""
+    text = (
+        rice._decode_chunk.lower(
+            _spec((nb, nbytes // 4), one_chip),
+            _spec((nb,), one_chip),
+            unpack_backend="pallas",
+        )
+        .compile()
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    assert "gather" not in text
